@@ -7,10 +7,16 @@ multiply -> inverse transforms; this module decides HOW each stage executes:
                    fused elementwise) -- the default everywhere.
   engine="pallas"  the hand-written TPU kernels take over the hot loops:
                    ``twiddle_pack`` for the r2r post-twiddle,
-                   ``fft_stockham`` for power-of-two (r)FFT backends, and
+                   ``fft_stockham`` for the (r)FFT lengths it compiles for
+                   on the engine's platform (``fft_stockham.fits``: powers
+                   of two, 512..1024 on the TPU), and
                    ``spectral_scale``/``green_multiply`` for the fused
-                   Green multiply.  Non-power-of-two FFT lengths fall back
-                   to jnp transparently, so any plan works on any engine.
+                   Green multiply.  Every other FFT length runs XLA's FFT
+                   by that plan-time rule, so any plan works on any engine.
+
+Every stage runs under a ``jax.named_scope`` named after its fault site
+(``fwd.<d>``, ``bwd.<d>``, ``green``); ``stage_map`` reads off a traced
+solve which stages run a Pallas kernel and which run XLA.
 
 A plan is compiled once into a ``TransformSchedule``: per-direction twiddle
 tables (plan-time numpy constants handed to the kernels) plus the combined
@@ -47,16 +53,20 @@ the original FLUPS / P3DFFT batched transform APIs.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
+import functools
+import re
+from dataclasses import dataclass, field
 
+import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.runtime import faults as _faults
 
 __all__ = ["TransformEngine", "TransformSchedule", "LayoutSchedule",
            "as_engine", "build_schedule", "schedule_layouts", "relayout",
            "on_last_axis", "folded_normfact", "fwd_1d", "bwd_1d",
-           "materialize_doubling", "crop_doubling", "ENGINES",
+           "materialize_doubling", "crop_doubling", "stage_map", "ENGINES",
            "RELAYOUT_MODES"]
 
 RELAYOUT_MODES = ("scheduled", "baseline")
@@ -68,17 +78,20 @@ ENGINES = ("xla", "pallas")
 class TransformEngine:
     """Execution backend selection for the transform + pointwise stages.
 
-    ``interpret``: run Pallas kernels in interpret mode (CPU validation);
-    on a real TPU runtime pass ``interpret=False`` to lower to Mosaic.
     ``max_radix``: Stockham FFT radix cap (4 = mixed radix-4/2, the
     default; 2 = pure radix-2, twice the stages at half the per-stage
     arithmetic) -- a plan-space search dimension (DESIGN.md #12); only
     the Pallas kernels consume it, the XLA engine ignores it.
+    ``platform``: the platform the solve runs on (default: JAX's default
+    backend; the distributed solver passes its mesh's).  It selects the
+    FFT lengths the Stockham kernel takes (``fft_stockham.fits``); whether
+    a kernel is compiled or interpreted follows the platform the program
+    is lowered for (``kernels.platform``).
     """
 
     name: str = "xla"
-    interpret: bool = True
     max_radix: int = 4
+    platform: str = field(default_factory=jax.default_backend)
 
     def __post_init__(self):
         if self.name not in ENGINES:
@@ -91,6 +104,12 @@ class TransformEngine:
     @property
     def use_pallas(self) -> bool:
         return self.name == "pallas"
+
+    def kernel_fft(self, n: int) -> bool:
+        """Whether a length-``n`` FFT runs in the Stockham kernel (the
+        plan-time routing rule); False on the XLA engine."""
+        from repro.kernels.fft_stockham import fits
+        return self.use_pallas and fits(n, self.platform)
 
 
 def as_engine(engine) -> TransformEngine:
@@ -142,68 +161,72 @@ def _fwd_last(x, p, sched=None):
     points (``n_pts`` deferred, ``n_fft`` when the plan pre-padded the
     Hockney doubling up front) and the outgoing axis carries ``p.n_out``.
     """
-    from . import transforms as tr
-    engine = sched.engine if sched is not None else None
-    x = _faults.taint(f"fwd.{p.dim}", x)
-    if engine is not None and engine.use_pallas:
-        _faults.fail_point(f"pallas.fwd.{p.dim}")
-    if p.pre_padded:
-        # dense up-front doubling: the zero extension is already in the
-        # array, the transform is a plain full-length one
+    with jax.named_scope(f"fwd.{p.dim}"):
+        from . import transforms as tr
+        engine = sched.engine if sched is not None else None
+        x = _faults.taint(f"fwd.{p.dim}", x)
+        if engine is not None and engine.use_pallas:
+            _faults.fail_point(f"pallas.fwd.{p.dim}")
+        if p.pre_padded:
+            # dense up-front doubling: the zero extension is already in the
+            # array, the transform is a plain full-length one
+            if p.category in ("sym", "semi"):
+                raise AssertionError("pre_padded is a DFT-direction mode")
+            return (tr._rfft(x, engine) if p.dft == "r2c"
+                    else tr._cfft(x, engine))
+        if p.flip:
+            x = x[..., ::-1]
+        x = x[..., p.in_start:p.in_start + p.n_in]
         if p.category in ("sym", "semi"):
-            raise AssertionError("pre_padded is a DFT-direction mode")
-        return tr._rfft(x, engine) if p.dft == "r2c" else tr._cfft(x, engine)
-    if p.flip:
-        x = x[..., ::-1]
-    x = x[..., p.in_start:p.in_start + p.n_in]
-    if p.category in ("sym", "semi"):
-        if p.n_fft > p.n_in:
-            pad = [(0, 0)] * (x.ndim - 1) + [(0, p.n_fft - p.n_in)]
-            x = jnp.pad(x, pad)
-        tables = sched.fwd_tables[p.dim] if sched is not None else None
-        return tr.r2r_forward(x, p.kind, engine=engine, tables=tables)
-    if p.dft == "r2c":
-        # pruned forward: the length-n_fft spectrum from the n_in nonzero
-        # inputs (Pallas skips the zero tail; XLA pads -- bit-identical)
-        return tr._rfft_padded(x, p.n_fft, engine)
-    return tr._cfft_padded(x, p.n_fft, engine)
+            if p.n_fft > p.n_in:
+                pad = [(0, 0)] * (x.ndim - 1) + [(0, p.n_fft - p.n_in)]
+                x = jnp.pad(x, pad)
+            tables = sched.fwd_tables[p.dim] if sched is not None else None
+            return tr.r2r_forward(x, p.kind, engine=engine, tables=tables)
+        if p.dft == "r2c":
+            # pruned forward: the length-n_fft spectrum from the n_in nonzero
+            # inputs (Pallas skips the zero tail; XLA pads -- bit-identical)
+            return tr._rfft_padded(x, p.n_fft, engine)
+        return tr._cfft_padded(x, p.n_fft, engine)
 
 
 def _bwd_last(y, p, sched=None):
     """Inverse 1-D transform of direction ``p`` on the LAST axis; emits
     ``p.valid_in`` points (the ``n_pts`` user axis under deferred doubling,
     the full ``n_fft`` reconstruction when the plan padded up front)."""
-    # NOTE: no normalization multiply here -- every direction's normfact is
-    # folded into the Green's function at plan time (build_green).
-    from . import transforms as tr
-    engine = sched.engine if sched is not None else None
-    y = _faults.taint(f"bwd.{p.dim}", y)
-    if engine is not None and engine.use_pallas:
-        _faults.fail_point(f"pallas.bwd.{p.dim}")
-    if p.category in ("sym", "semi"):
-        tables = sched.bwd_tables[p.dim] if sched is not None else None
-        x = tr.r2r_backward(y, p.kind, engine=engine, tables=tables)
-        x = x[..., :p.n_in]
-    elif p.pre_padded:
-        # dense mode keeps the doubled extent; cropped once at solve end
-        return (tr._irfft(y, p.n_fft, engine) if p.dft == "r2c"
-                else tr._cfft(y, engine, inverse=True))
-    elif p.dft == "r2c":
-        # pruned backward: reconstruct only the n_in retained samples
-        x = tr._irfft_crop(y, p.n_fft, p.n_in, engine)
-    else:
-        x = tr._icfft_crop(y, p.n_in, engine)
-    # place into the user-sized axis
-    left = p.in_start
-    right = p.n_pts - p.in_start - p.n_in - (1 if p.per_dup else 0)
-    if left or right:
-        pad = [(0, 0)] * (x.ndim - 1) + [(left, right)]
-        x = jnp.pad(x, pad)
-    if p.per_dup:  # node-periodic: duplicate the first point at the end
-        x = jnp.concatenate([x, x[..., :1]], axis=-1)
-    if p.flip:
-        x = x[..., ::-1]
-    return x
+    with jax.named_scope(f"bwd.{p.dim}"):
+        # NOTE: no normalization multiply here -- every direction's
+        # normfact is folded into the Green's function at plan time
+        # (build_green).
+        from . import transforms as tr
+        engine = sched.engine if sched is not None else None
+        y = _faults.taint(f"bwd.{p.dim}", y)
+        if engine is not None and engine.use_pallas:
+            _faults.fail_point(f"pallas.bwd.{p.dim}")
+        if p.category in ("sym", "semi"):
+            tables = sched.bwd_tables[p.dim] if sched is not None else None
+            x = tr.r2r_backward(y, p.kind, engine=engine, tables=tables)
+            x = x[..., :p.n_in]
+        elif p.pre_padded:
+            # dense mode keeps the doubled extent; cropped once at solve end
+            return (tr._irfft(y, p.n_fft, engine) if p.dft == "r2c"
+                    else tr._cfft(y, engine, inverse=True))
+        elif p.dft == "r2c":
+            # pruned backward: reconstruct only the n_in retained samples
+            x = tr._irfft_crop(y, p.n_fft, p.n_in, engine)
+        else:
+            x = tr._icfft_crop(y, p.n_in, engine)
+        # place into the user-sized axis
+        left = p.in_start
+        right = p.n_pts - p.in_start - p.n_in - (1 if p.per_dup else 0)
+        if left or right:
+            pad = [(0, 0)] * (x.ndim - 1) + [(left, right)]
+            x = jnp.pad(x, pad)
+        if p.per_dup:  # node-periodic: duplicate the first point at the end
+            x = jnp.concatenate([x, x[..., :1]], axis=-1)
+        if p.flip:
+            x = x[..., ::-1]
+        return x
 
 
 def fwd_1d(x, p, sched=None):
@@ -329,6 +352,29 @@ def crop_doubling(x, dirs):
     return x
 
 
+def pin_row_major(x):
+    """``x`` with its layout pinned to row-major (values unchanged).
+
+    Every stage of the schedule returns its output through this.  Left to
+    choose, XLA on a TPU v5e (jax 0.9.0) gave the backward stages of the
+    256^3 unbounded solve transposed layouts, and the crop-and-switch
+    fusion it emitted for them computed a wrong field (relative E_inf 0.25
+    where the scheme gives 1.3e-4); with row-major stage outputs the same
+    program is exact to f32, for about 6% more time per solve.  The TPU's
+    layout constraint takes no complex operand, so a complex array is
+    pinned plane by plane."""
+    if jnp.iscomplexobj(x):
+        return jax.lax.complex(pin_row_major(x.real), pin_row_major(x.imag))
+    return with_layout_constraint(x, Layout(tuple(range(x.ndim))))
+
+
+def _pinned(stage):
+    @functools.wraps(stage)
+    def run(*args, **kwargs):
+        return pin_row_major(stage(*args, **kwargs))
+    return run
+
+
 @dataclass(frozen=True)
 class TransformSchedule:
     """Plan-time constants for one solve: per-direction twiddle tables, the
@@ -351,6 +397,7 @@ class TransformSchedule:
     # bit-exact.  With a collector the stage runs under its linearity /
     # Parseval sandwich with inline selective recompute.
 
+    @_pinned
     def fwd_chunk(self, x, d: int, col=None, tol=None):
         """Forward 1-D transform of logical direction ``d`` on a full block
         or an uninvolved-axis chunk (the overlap strategy's stage unit), in
@@ -360,6 +407,7 @@ class TransformSchedule:
             return abft.checked_fwd_chunk(x, d, self, col, tol)
         return fwd_1d(x, self.dirs[d], self)
 
+    @_pinned
     def bwd_chunk(self, x, d: int, col=None, tol=None):
         """Inverse 1-D transform of logical direction ``d``; chunk-safe."""
         if col is not None:
@@ -367,6 +415,7 @@ class TransformSchedule:
             return abft.checked_bwd_chunk(x, d, self, col, tol)
         return bwd_1d(x, self.dirs[d], self)
 
+    @_pinned
     def fwd_last(self, x, d: int, col=None, tol=None):
         """Forward 1-D transform of direction ``d`` on the LAST axis (the
         layout-scheduled stage unit: the pipeline guarantees the active
@@ -376,6 +425,7 @@ class TransformSchedule:
             return abft.checked_fwd_last(x, d, self, col, tol)
         return _fwd_last(x, self.dirs[d], self)
 
+    @_pinned
     def bwd_last(self, x, d: int, col=None, tol=None):
         """Inverse 1-D transform of direction ``d`` on the LAST axis."""
         if col is not None:
@@ -387,20 +437,21 @@ class TransformSchedule:
     # is the physical extent a topology switch ships for dim ``d`` (see
     # Plan1D; spectral extents are the plain ``n_out`` field)
 
+    @_pinned
     def green_multiply(self, yhat, green, col=None, tol=None):
         """The fused pointwise pass (Green x normalization in one multiply)."""
         if col is not None:
             from repro.runtime import abft
             return abft.checked_green(yhat, green, self, col, tol)
-        yhat = _faults.taint("green", yhat)
-        if self.engine.use_pallas:
-            _faults.fail_point("pallas.green")
-            from repro.kernels import ops
-            return ops.green_multiply(yhat, green,
-                                      interpret=self.engine.interpret)
-        if jnp.iscomplexobj(yhat):
-            return yhat * green
-        return yhat * green.astype(yhat.dtype)
+        with jax.named_scope("green"):
+            yhat = _faults.taint("green", yhat)
+            if self.engine.use_pallas:
+                _faults.fail_point("pallas.green")
+                from repro.kernels import ops
+                return ops.green_multiply(yhat, green)
+            if jnp.iscomplexobj(yhat):
+                return yhat * green
+            return yhat * green.astype(yhat.dtype)
 
     def can_fuse_green(self, d: int) -> bool:
         """True when the forward transform of ``d`` can run the Green
@@ -409,12 +460,12 @@ class TransformSchedule:
         (the Hockney zero-tail first stage composes with the epilogue)."""
         p = self.dirs[d]
         n = p.n_fft
-        return (self.engine.use_pallas
+        return (self.engine.kernel_fft(n)
                 and p.category in ("per", "unb")
-                and n >= 2 and (n & (n - 1)) == 0
                 and not p.flip and p.in_start == 0
                 and (p.n_in == n or n == 2 * p.n_in))
 
+    @_pinned
     def fwd_last_green(self, x, d: int, green, col=None, tol=None):
         """Forward transform of the LAST forward direction fused with the
         Green multiply: on the Pallas engine the ``spectral_scale`` pass
@@ -432,22 +483,55 @@ class TransformSchedule:
         if (not self.can_fuse_green(d)
                 or bool(jnp.iscomplexobj(x)) != want_cplx):
             return self.green_multiply(self.fwd_last(x, d), green)
-        x = _faults.taint(f"fwd.{p.dim}", x)
-        x = _faults.taint("green", x)
-        _faults.fail_point(f"pallas.fwd.{p.dim}")
-        _faults.fail_point("pallas.green")
-        from repro.kernels import ops
-        n_live = p.n_fft if p.pre_padded else p.n_in
-        x = x[..., :n_live]
-        pad_to = None if n_live == p.n_fft else p.n_fft
-        assert green.shape[-1] == p.n_out, (green.shape, p.n_out)
-        if p.dft == "r2c":
-            return ops.rfft_green(x, green, interpret=self.engine.interpret,
-                                  pad_to=pad_to,
-                                  max_radix=self.engine.max_radix)
-        return ops.fft1d_green(x, green, interpret=self.engine.interpret,
-                               pad_to=pad_to,
-                               max_radix=self.engine.max_radix)
+        with jax.named_scope(f"fwd.{p.dim}+green"):
+            x = _faults.taint(f"fwd.{p.dim}", x)
+            x = _faults.taint("green", x)
+            _faults.fail_point(f"pallas.fwd.{p.dim}")
+            _faults.fail_point("pallas.green")
+            from repro.kernels import ops
+            n_live = p.n_fft if p.pre_padded else p.n_in
+            x = x[..., :n_live]
+            pad_to = None if n_live == p.n_fft else p.n_fft
+            assert green.shape[-1] == p.n_out, (green.shape, p.n_out)
+            fused = ops.rfft_green if p.dft == "r2c" else ops.fft1d_green
+            return fused(x, green, pad_to=pad_to,
+                         max_radix=self.engine.max_radix)
+
+
+_STAGE = re.compile(r"(?:^|/)((?:fwd|bwd)\.\d(?:\+green)?|green)(?=/|$)")
+
+
+def stage_map(jaxpr) -> dict:
+    """Which stages of a traced solve run a Pallas kernel and which XLA.
+
+    ``jaxpr`` is the ``jax.make_jaxpr`` of a solve.  Every equation inside
+    a stage's named scope counts: a ``pallas_call`` marks the stage
+    "pallas", an XLA ``fft`` marks it "xla" (a stage with both, e.g. a
+    kernel FFT beside an XLA FFT of a routed length, is "pallas+xla"; a
+    stage with neither -- the XLA engine's Green multiply -- is "xla").
+    Returns ``{stage: kind}`` in the order the stages were traced."""
+    found: dict = {}
+
+    def walk(jx, prefix):
+        for eqn in jx.eqns:
+            stack = prefix + "/" + str(eqn.source_info.name_stack)
+            m = _STAGE.findall(stack)
+            kinds = found.setdefault(m[-1], set()) if m else None
+            name = eqn.primitive.name
+            if name == "pallas_call":
+                if kinds is not None:
+                    kinds.add("pallas")
+                continue                  # the kernel body is not a stage
+            if name == "fft" and kinds is not None:
+                kinds.add("xla")
+            for v in eqn.params.values():
+                for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub, stack)
+
+    walk(jaxpr.jaxpr, "")
+    return {k: "+".join(sorted(v)) or "xla" for k, v in found.items()}
 
 
 def folded_normfact(plan) -> float:

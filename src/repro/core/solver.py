@@ -371,16 +371,18 @@ def build_green(plan: PoissonPlan) -> np.ndarray:
     for d in phys_dims:
         g = g * dirs[d].h
 
-    # transform along unbounded-ish dirs
-    for d in phys_dims:
+    # transform along unbounded-ish dirs; the kernel is even-symmetric, so
+    # every spectrum is real.  The r2c dir goes first, by a real FFT that
+    # keeps only its n_out = n_fft//2 + 1 bins, so the later transforms
+    # run on half the data; all of them use every core (at the four-chip
+    # size the kernel holds 2^30 points)
+    for d in sorted(phys_dims, key=lambda d: dirs[d].dft != "r2c"):
         p = dirs[d]
         if p.category == "unb":
-            gh = np.fft.fft(g, axis=d)
-            g = gh.real  # kernel is even-symmetric -> real spectrum
             if p.dft == "r2c":
-                sl = [slice(None)] * g.ndim
-                sl[d] = slice(0, p.n_out)
-                g = g[tuple(sl)]
+                g = sfft.rfft(g, axis=d, workers=-1).real
+            else:
+                g = sfft.fft(g, axis=d, workers=-1).real
         else:  # semi
             g = _green_dct1_align(g, d, p)
     return g * norm

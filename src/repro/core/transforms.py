@@ -20,12 +20,13 @@ The pencil engine always shuffles the active direction to the last axis
 
 Engine selection: every public transform takes ``engine=None`` (pure XLA) or
 a ``repro.core.engine.TransformEngine``; ``engine="pallas"`` routes the
-post-twiddle through the ``twiddle_pack`` Pallas kernel and power-of-two
-rfft/irfft through the ``fft_stockham`` kernel (see ``repro.kernels.ops``).
-On power-of-two lengths the forward post-twiddle kinds (dct1/dct2/dst2)
-run the FUSED ``rfft_twiddle`` kernel instead -- the twiddle executes in
-the FFT's final-stage registers, one HBM round trip instead of three
-(DESIGN.md #9).
+post-twiddle through the ``twiddle_pack`` Pallas kernel and the FFT lengths
+the ``fft_stockham`` kernel takes on the engine's platform
+(``TransformEngine.kernel_fft``) through that kernel (see
+``repro.kernels.ops``); every other length runs ``jnp.fft``.  On kernel
+lengths the forward post-twiddle kinds (dct1/dct2/dst2) run the FUSED
+``rfft_twiddle`` kernel instead -- the twiddle executes in the FFT's
+final-stage registers, one HBM round trip instead of three (DESIGN.md #9).
 """
 from __future__ import annotations
 
@@ -51,8 +52,9 @@ def _use_pallas(engine) -> bool:
     return engine is not None and getattr(engine, "use_pallas", False)
 
 
-def _pow2(n: int) -> bool:
-    return n >= 2 and (n & (n - 1)) == 0
+def _kernel(engine, n: int) -> bool:
+    """Whether the engine runs a length-``n`` FFT in the Stockham kernel."""
+    return engine is not None and engine.kernel_fft(n)
 
 
 def _scan_dtype(dtype):
@@ -69,18 +71,16 @@ def _scan_dtype(dtype):
 # ---------------------------------------------------------------------------
 
 def _rfft(z, engine):
-    if _use_pallas(engine) and _pow2(z.shape[-1]):
+    if _kernel(engine, z.shape[-1]):
         from repro.kernels import ops
-        return ops.rfft_pallas(z, interpret=engine.interpret,
-                               max_radix=engine.max_radix)
+        return ops.rfft_pallas(z, max_radix=engine.max_radix)
     return jnp.fft.rfft(z, axis=-1)
 
 
 def _irfft(c, n, engine):
-    if _use_pallas(engine) and _pow2(n):
+    if _kernel(engine, n):
         from repro.kernels import ops
-        return ops.irfft_pallas(c, n, interpret=engine.interpret,
-                                max_radix=engine.max_radix)
+        return ops.irfft_pallas(c, n, max_radix=engine.max_radix)
     return jnp.fft.irfft(c, n=n, axis=-1)
 
 
@@ -89,10 +89,9 @@ def _cfft(z, engine, inverse=False):
     if not jnp.iscomplexobj(z):
         z = z.astype(jnp.complex128 if z.dtype == jnp.float64
                      else jnp.complex64)
-    if _use_pallas(engine) and _pow2(z.shape[-1]):
+    if _kernel(engine, z.shape[-1]):
         from repro.kernels import ops
-        return ops.fft1d(z, inverse=inverse, interpret=engine.interpret,
-                         max_radix=engine.max_radix)
+        return ops.fft1d(z, inverse=inverse, max_radix=engine.max_radix)
     return (jnp.fft.ifft if inverse else jnp.fft.fft)(z, axis=-1)
 
 
@@ -116,10 +115,9 @@ def _rfft_padded(x, n_fft, engine):
     n_in = x.shape[-1]
     if n_in == n_fft:
         return _rfft(x, engine)
-    if _use_pallas(engine) and _pow2(n_fft) and n_fft == 2 * n_in:
+    if _kernel(engine, n_fft) and n_fft == 2 * n_in:
         from repro.kernels import ops
-        return ops.rfft_pallas(x, pad_to=n_fft, interpret=engine.interpret,
-                               max_radix=engine.max_radix)
+        return ops.rfft_pallas(x, pad_to=n_fft, max_radix=engine.max_radix)
     return _rfft(_zpad(x, n_fft), engine)
 
 
@@ -128,25 +126,23 @@ def _cfft_padded(z, n_fft, engine):
     n_in = z.shape[-1]
     if n_in == n_fft:
         return _cfft(z, engine)
-    if (_use_pallas(engine) and _pow2(n_fft) and n_fft == 2 * n_in
+    if (_kernel(engine, n_fft) and n_fft == 2 * n_in
             and jnp.iscomplexobj(z)):
         from repro.kernels import ops
-        return ops.fft1d(z, pad_to=n_fft, interpret=engine.interpret,
-                         max_radix=engine.max_radix)
+        return ops.fft1d(z, pad_to=n_fft, max_radix=engine.max_radix)
     return _cfft(_zpad(z, n_fft), engine)
 
 
 def _irfft_crop(y, n_fft, keep, engine):
     """First ``keep`` samples of the length-``n_fft`` irfft.  The Pallas
     engine reconstructs only the retained half via the parity split (two
-    half-length inverse FFTs); XLA reconstructs fully and crops."""
+    half-length inverse FFTs, so the kernel runs at ``n_fft // 2``); XLA
+    reconstructs fully and crops."""
     if keep >= n_fft:
         return _irfft(y, n_fft, engine)
-    if (_use_pallas(engine) and _pow2(n_fft) and n_fft >= 4
-            and keep <= n_fft // 2):
+    if _kernel(engine, n_fft // 2) and keep <= n_fft // 2:
         from repro.kernels import ops
-        return ops.irfft_pruned(y, n_fft, keep, interpret=engine.interpret,
-                                max_radix=engine.max_radix)
+        return ops.irfft_pruned(y, n_fft, keep, max_radix=engine.max_radix)
     return _irfft(y, n_fft, engine)[..., :keep]
 
 
@@ -155,11 +151,9 @@ def _icfft_crop(z, keep, engine):
     n_fft = z.shape[-1]
     if keep >= n_fft:
         return _cfft(z, engine, inverse=True)
-    if (_use_pallas(engine) and _pow2(n_fft) and n_fft >= 4
-            and keep <= n_fft // 2):
+    if _kernel(engine, n_fft // 2) and keep <= n_fft // 2:
         from repro.kernels import ops
-        return ops.ifft_pruned(z, keep, interpret=engine.interpret,
-                               max_radix=engine.max_radix)
+        return ops.ifft_pruned(z, keep, max_radix=engine.max_radix)
     return _cfft(z, engine, inverse=True)[..., :keep]
 
 
@@ -167,8 +161,7 @@ def _post(re, im, a, b, engine, out_dtype):
     """y = a * re + b * im along the last axis (the r2r post-twiddle)."""
     if _use_pallas(engine):
         from repro.kernels import ops
-        return ops.post_twiddle(re, im, a, b,
-                                interpret=engine.interpret).astype(out_dtype)
+        return ops.post_twiddle(re, im, a, b).astype(out_dtype)
     av = jnp.asarray(a, dtype=out_dtype)
     bv = jnp.asarray(b, dtype=out_dtype)
     return (av * re + bv * im).astype(out_dtype)
@@ -241,11 +234,10 @@ def _rfft_twiddle_fused(z, a, b, start, count, engine, out_dtype):
     """Fused rfft + post-twiddle (``a*re + b*im`` over ``count`` bins from
     ``start``) when the Pallas engine can run it as ONE kernel; None when
     the caller must take the unfused rfft + ``_post`` path."""
-    if not (_use_pallas(engine) and _pow2(z.shape[-1])):
+    if not _kernel(engine, z.shape[-1]):
         return None
     from repro.kernels import ops
     return ops.rfft_twiddle(z, a[:count], b[:count], start=start,
-                            interpret=engine.interpret,
                             max_radix=engine.max_radix).astype(out_dtype)
 
 

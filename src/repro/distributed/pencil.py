@@ -41,13 +41,14 @@ CPU-cluster deployment path would use).
 """
 from __future__ import annotations
 
+import dataclasses
 import time
 from functools import partial
 
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P, NamedSharding
+from jax.sharding import AxisType, PartitionSpec as P, NamedSharding
 
 from repro.core.bc import DataLayout
 from repro.core import green as gr
@@ -63,6 +64,15 @@ __all__ = ["DistributedPoissonSolver"]
 
 def _pad_to(n: int, p: int) -> int:
     return -(-n // p) * p
+
+
+def _auto_axes(mesh):
+    """``mesh`` with every axis Auto.  ``jax.make_mesh`` builds Explicit
+    axes, under which the eager pads and crops around the shard_map (a
+    slice of a sharded axis) raise ``ShardingTypeError``; the solver's
+    arrays only need XLA to place them, and the shard_map body is manual
+    whatever the axis type."""
+    return mesh.update(axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 # axis pad/crop shared with the comm chunking layer
@@ -114,6 +124,9 @@ class DistributedPoissonSolver:
         assert verify in (None, "nan", "residual", "abft",
                           "abft-stages"), verify
         assert autotune_search in ("guided", "brute"), autotune_search
+        # the engine routes FFT lengths by the platform the mesh's devices
+        # are on (they may be described devices of an ahead-of-time compile)
+        self._platform = mesh.devices.flat[0].platform
         # full construction identity, kept for _configure (ladder rebuilds)
         # and rebuild(mesh) (elastic recovery re-plans)
         self._ctor = dict(shape=tuple(shape), L=L, bcs=bcs, layout=layout,
@@ -121,7 +134,7 @@ class DistributedPoissonSolver:
                           batch_axis=batch_axis, eps_factor=eps_factor,
                           dtype=dtype, lazy_green=lazy_green,
                           order_policy=order_policy, comm_req=comm,
-                          engine_obj=as_engine(engine),
+                          engine_obj=self._engine(engine),
                           autotune_candidates=autotune_candidates,
                           autotune_cache=autotune_cache,
                           autotune_batch=autotune_batch,
@@ -133,7 +146,11 @@ class DistributedPoissonSolver:
         self.abft_rtol = float(abft_rtol)
         self.stats = {"solves": 0, "retries": 0, "verify_failures": 0,
                       "degradations": []}
-        self.mesh = mesh
+        # the mesh as the caller passed it keys the get_solver cache (and
+        # its eviction on rebuild); the solver places its arrays on the
+        # Auto-axis view of it
+        self._caller_mesh = mesh
+        self.mesh = _auto_axes(mesh)
         self.axes = tuple(axes)
         self.batch_axis = batch_axis
         self.dtype = dtype
@@ -143,6 +160,9 @@ class DistributedPoissonSolver:
         self._green_raw = _green_cache
         self._configure({"engine": as_engine(engine).name, "comm": None,
                          "doubling": doubling, "relayout": relayout})
+
+    def _engine(self, engine):
+        return dataclasses.replace(as_engine(engine), platform=self._platform)
 
     def _configure(self, cfg: dict):
         """(Re)build plan, Green layout, comm strategy and jits for one
@@ -165,7 +185,7 @@ class DistributedPoissonSolver:
         base_eng = c.get("engine_obj")
         self.engine = (base_eng if base_eng is not None
                        and base_eng.name == cfg["engine"]
-                       else as_engine(cfg["engine"]))
+                       else self._engine(cfg["engine"]))
         self.schedule = build_schedule(self.plan, self.engine)
         self.relayout = cfg["relayout"]
         e = self.plan.order
@@ -408,20 +428,14 @@ class DistributedPoissonSolver:
         local = partial(body, cfg=cfg)
         if self.batch_axis is not None:
             local = jax.vmap(local, in_axes=(0, None))
-        shard_map = getattr(jax, "shard_map", None)
-        if shard_map is None:  # jax < 0.6: experimental namespace
-            from jax.experimental.shard_map import shard_map
-        smap_kw = {}
-        if self.engine.use_pallas:
-            # pallas_call has no replication rule on older jax releases
-            import inspect
-            if "check_rep" in inspect.signature(shard_map).parameters:
-                smap_kw["check_rep"] = False
         in_spec = self.input_spec(local_batch)
-        fn = shard_map(
+        # the varying-manual-axes checker is off: pallas_call has no rule
+        # for it, and the transpose of jnp.fft drops the annotation, so
+        # the ABFT sandwich's vjp of this body fails type checking
+        fn = jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(in_spec, self.g_spec),
-            out_specs=in_spec, **smap_kw)
+            out_specs=in_spec, check_vma=False)
         return jax.jit(fn, donate_argnums=(0,) if donate else ())
 
     def _abft_tol(self) -> float:
@@ -469,20 +483,13 @@ class DistributedPoissonSolver:
             rep_spec = P(self.batch_axis, None)
         else:
             rep_spec = P()
-        shard_map = getattr(jax, "shard_map", None)
-        if shard_map is None:  # jax < 0.6: experimental namespace
-            from jax.experimental.shard_map import shard_map
-        smap_kw = {}
-        import inspect
-        if "check_rep" in inspect.signature(shard_map).parameters:
-            # the report is replicated by construction (pmax over both
-            # axes); skip the replication checker, it cannot see that
-            smap_kw["check_rep"] = False
         in_spec = self.input_spec(local_batch)
-        fn = shard_map(
+        # the report is replicated by construction (pmax over both axes);
+        # the checker cannot see that, so it is off
+        fn = jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(in_spec, self.g_spec),
-            out_specs=(in_spec, rep_spec), **smap_kw)
+            out_specs=(in_spec, rep_spec), check_vma=False)
         return jax.jit(fn, donate_argnums=(0,)), holder
 
     def _lite_pair(self, fp_shape, local_batch: bool):
@@ -866,7 +873,7 @@ class DistributedPoissonSolver:
         solver bound to dead devices.
         """
         from repro.core.solver import evict_solver_entries
-        evict_solver_entries(self.mesh)
+        evict_solver_entries(self._caller_mesh)
         c = self._ctor
         new = DistributedPoissonSolver(
             c["shape"], c["L"], c["bcs"], c["layout"], c["green_kind"],
@@ -915,6 +922,20 @@ class DistributedPoissonSolver:
         spec = self.input_spec(local_batch)
         f = jax.ShapeDtypeStruct(shp, dtype,
                                  sharding=NamedSharding(self.mesh, spec))
-        g = jax.ShapeDtypeStruct(self._green_np.shape, self._green_np.dtype,
-                                 sharding=NamedSharding(self.mesh, self.g_spec))
-        return self.jit_for(local_batch).lower(f, g)
+        return self.jit_for(local_batch).lower(f, self._green_shape())
+
+    def _green_shape(self):
+        return jax.ShapeDtypeStruct(
+            self._green_np.shape, self._green_np.dtype,
+            sharding=NamedSharding(self.mesh, self.g_spec))
+
+    def stage_map(self) -> dict:
+        """Which stages of the (unbatched) solve run a Pallas kernel and
+        which run XLA, read off the traced pipeline (``engine.stage_map``):
+        ``{"fwd.0": "pallas", ..., "green": "pallas", ...}``."""
+        from repro.core.engine import stage_map
+        f = jax.ShapeDtypeStruct(
+            self.padded_input_shape(), self.dtype,
+            sharding=NamedSharding(self.mesh, self.input_spec()))
+        return stage_map(jax.make_jaxpr(self.jit_for())(
+            f, self._green_shape()))

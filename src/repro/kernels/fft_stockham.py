@@ -22,9 +22,17 @@ round trip each (flups' shuffle/pack folded into the transform itself):
   ``spectral_scale`` kernel's job) scaling the ``[start, start+k)`` bins by
   a per-(row, bin) real plane, shared across any leading batch.
 
-Complex data is (re, im) f32 pairs.  Twiddles are computed at trace time as
-constants folded into the kernel (N is static).  VMEM budget: a
-(8, 4096) block is 8 * 4096 * 2 * 4B * ~3 live buffers ~= 0.8 MB.
+Complex data is (re, im) f32 pairs.  Twiddles are computed in-kernel from
+an integer iota (Mosaic's ``tpu.iota`` takes integer types only) cast to
+the data dtype; N is static.
+
+Mosaic compiles the kernel on the TPU for the lengths in ``TPU_LENGTHS``
+(``tests/test_tpu_compile.py`` compiles them for a described v5e): below
+512 the radix-4 stages' minor-dim reshapes are refused ("unsupported
+shape cast"), above 1024 the kernel runs out of VMEM.  ``fits(n,
+platform)`` is the plan-time rule the transform layer routes by; every
+other length runs XLA's FFT.  In interpret mode (any other platform)
+every power of two runs.
 """
 from __future__ import annotations
 
@@ -34,6 +42,26 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .platform import pallas_call
+
+# power-of-two FFT lengths the kernel compiles for on the TPU (inclusive)
+TPU_LENGTHS = (512, 1024)
+
+
+def fits(n: int, platform: str) -> bool:
+    """Whether a length-``n`` FFT runs in this kernel on ``platform``: a
+    power of two, within ``TPU_LENGTHS`` on the TPU."""
+    if n < 2 or n & (n - 1):
+        return False
+    if platform == "tpu":
+        return TPU_LENGTHS[0] <= n <= TPU_LENGTHS[1]
+    return True
+
+
+def _iota(n, dtype):
+    """``0, 1, ..., n-1`` in ``dtype`` via an integer iota."""
+    return jnp.arange(n, dtype=jnp.int32).astype(dtype)
 
 
 def _stages(n):
@@ -67,7 +95,7 @@ def _fft_body(xr, xi, *, n, inverse, n_in=None, max_radix=4):
     if n_in is not None and n_in < n:
         assert n == 2 * n_in and not inverse
         half = n // 2
-        ang = jnp.arange(half, dtype=xr.dtype) * xr.dtype.type(sign)
+        ang = _iota(half, xr.dtype) * xr.dtype.type(sign)
         wr = jnp.cos(ang)
         wi = jnp.sin(ang)
         # x1 == 0: e = x0, d = x0 * w  (the skipped butterflies)
@@ -100,8 +128,7 @@ def _fft_body(xr, xi, *, n, inverse, n_in=None, max_radix=4):
                 u3r, u3i = -t3i, t3r
             else:           # -i * t3
                 u3r, u3i = t3i, -t3r
-            ang = (jnp.arange(q, dtype=xr.dtype) *
-                   xr.dtype.type(sign * (n // m)))
+            ang = _iota(q, xr.dtype) * xr.dtype.type(sign * (n // m))
             w1r = jnp.cos(ang)[None, :, None]
             w1i = jnp.sin(ang)[None, :, None]
             w2r = jnp.cos(2.0 * ang)[None, :, None]
@@ -137,8 +164,7 @@ def _fft_body(xr, xi, *, n, inverse, n_in=None, max_radix=4):
         x0i, x1i = xi3[:, :half, :], xi3[:, half:, :]
         # twiddles computed in-kernel (iota -> cos/sin on the VPU); n, m
         # are static so sign*(n//m) folds to an immediate
-        ang = (jnp.arange(half, dtype=xr.dtype) *
-               xr.dtype.type(sign * (n // m)))
+        ang = _iota(half, xr.dtype) * xr.dtype.type(sign * (n // m))
         wr = jnp.cos(ang)[None, :, None]
         wi = jnp.sin(ang)[None, :, None]
         er, ei = x0r + x1r, x0i + x1i
@@ -199,8 +225,8 @@ def _pruned(n, pad_to, inverse):
     return pad_to, n
 
 
-def fft_stockham(re, im, batch_block=8, inverse=False, interpret=True,
-                 pad_to=None, max_radix=4):
+def fft_stockham(re, im, batch_block=8, inverse=False, pad_to=None,
+                 max_radix=4):
     """re/im: (batch, N) f32 -> (re, im) of the complex FFT along axis -1.
 
     ``pad_to = 2 * N`` computes the length-``pad_to`` FFT of the signal
@@ -215,7 +241,7 @@ def fft_stockham(re, im, batch_block=8, inverse=False, interpret=True,
     grid = (pl.cdiv(b, bb),)
     spec_in = pl.BlockSpec((bb, n), lambda i: (i, 0))
     spec_out = pl.BlockSpec((bb, n_out), lambda i: (i, 0))
-    fn = pl.pallas_call(
+    fn = pallas_call(
         partial(_kernel, n=n_out, inverse=inverse, n_in=n_in,
                 max_radix=max_radix),
         grid=grid,
@@ -223,13 +249,12 @@ def fft_stockham(re, im, batch_block=8, inverse=False, interpret=True,
         out_specs=[spec_out, spec_out],
         out_shape=[jax.ShapeDtypeStruct((b, n_out), re.dtype),
                    jax.ShapeDtypeStruct((b, n_out), im.dtype)],
-        interpret=interpret,
     )
     return fn(re, im)
 
 
 def fft_stockham_twiddle(re, im, a, b, start=0, batch_block=8,
-                         interpret=True, pad_to=None, max_radix=4):
+                         pad_to=None, max_radix=4):
     """Forward FFT fused with the r2r post-twiddle epilogue.
 
     re/im: (batch, N); a/b: (k,) twiddle tables.  Returns the real
@@ -245,20 +270,19 @@ def fft_stockham_twiddle(re, im, a, b, start=0, batch_block=8,
     grid = (pl.cdiv(bsz, bb),)
     spec_in = pl.BlockSpec((bb, n), lambda i: (i, 0))
     vec = pl.BlockSpec((1, k), lambda i: (0, 0))
-    fn = pl.pallas_call(
+    fn = pallas_call(
         partial(_kernel_twiddle, n=n_out, n_in=n_in, start=start, k=k,
                 max_radix=max_radix),
         grid=grid,
         in_specs=[spec_in, spec_in, vec, vec],
         out_specs=pl.BlockSpec((bb, k), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((bsz, k), re.dtype),
-        interpret=interpret,
     )
     return fn(re, im, a.reshape(1, k), b.reshape(1, k))
 
 
-def fft_stockham_scale(re, im, g, start=0, batch_block=8, interpret=True,
-                       pad_to=None, max_radix=4):
+def fft_stockham_scale(re, im, g, start=0, batch_block=8, pad_to=None,
+                       max_radix=4):
     """Forward FFT fused with the spectral Green-multiply epilogue.
 
     re/im: (rows, N); g: (grows, k) with rows % grows == 0 (leading
@@ -280,7 +304,7 @@ def fft_stockham_scale(re, im, g, start=0, batch_block=8, interpret=True,
     spec_in = pl.BlockSpec((1, bb, n), lambda b_, i: (b_, i, 0))
     spec_out = pl.BlockSpec((1, bb, k), lambda b_, i: (b_, i, 0))
     gspec = pl.BlockSpec((bb, k), lambda b_, i: (i, 0))
-    fn = pl.pallas_call(
+    fn = pallas_call(
         partial(_kernel_scale, n=n_out, n_in=n_in, start=start, k=k,
                 max_radix=max_radix),
         grid=grid,
@@ -288,7 +312,6 @@ def fft_stockham_scale(re, im, g, start=0, batch_block=8, interpret=True,
         out_specs=[spec_out, spec_out],
         out_shape=[jax.ShapeDtypeStruct((nb, grows, k), re.dtype),
                    jax.ShapeDtypeStruct((nb, grows, k), im.dtype)],
-        interpret=interpret,
     )
     orr, oi = fn(re3, im3, g)
     return orr.reshape(rows, k), oi.reshape(rows, k)

@@ -1,12 +1,13 @@
 """Jitted public wrappers for the Pallas kernels (+ dtype plumbing).
 
-``interpret=True`` everywhere in this environment: the kernel bodies
-execute on CPU for validation; on a real TPU runtime the same calls lower
-to Mosaic with the declared BlockSpecs.
+The kernels are Mosaic-compiled on the TPU and interpreted on every other
+platform (``kernels.platform``), decided for the platform each program is
+lowered for.
 
-All wrappers preserve the input dtype (f64 runs fine in interpret mode;
-on a real TPU the solver feeds f32), so ``engine="pallas"`` matches
-``engine="xla"`` to roundoff instead of truncating to f32.
+All wrappers preserve the input dtype (f64 runs in interpret mode, where
+the CPU test suite checks the kernels; on the TPU the solver feeds f32),
+so ``engine="pallas"`` matches ``engine="xla"`` to roundoff instead of
+truncating to f32.
 """
 from __future__ import annotations
 
@@ -46,8 +47,8 @@ def green_checksum(fhat, green):
     return jnp.sum(fhat * g)
 
 
-@partial(jax.jit, static_argnames=("scale", "interpret"))
-def green_multiply(fhat, green, scale: float = 1.0, interpret: bool = True):
+@partial(jax.jit, static_argnames=("scale",))
+def green_multiply(fhat, green, scale: float = 1.0):
     """Complex (or real) spectral field times real Green + norm factor.
 
     The only O(N^3) pointwise pass of the solve: one fused kernel instead
@@ -68,16 +69,16 @@ def green_multiply(fhat, green, scale: float = 1.0, interpret: bool = True):
         g2 = green.reshape(grows, lanes).astype(rdt)
         re = fhat.real.reshape(kshape).astype(rdt)
         im = fhat.imag.reshape(kshape).astype(rdt)
-        orr, oi = spectral_scale(re, im, g2, scale, interpret=interpret)
+        orr, oi = spectral_scale(re, im, g2, scale)
         return (orr + 1j * oi).reshape(shp).astype(fhat.dtype)
     g2 = green.reshape(grows, lanes).astype(fhat.dtype)
     re = fhat.reshape(kshape)
-    orr, _ = spectral_scale(re, re, g2, scale, interpret=interpret)
+    orr, _ = spectral_scale(re, re, g2, scale)
     return orr.reshape(shp).astype(fhat.dtype)
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def post_twiddle(re, im, a, b, interpret: bool = True):
+@jax.jit
+def post_twiddle(re, im, a, b):
     """Generic r2r post-twiddle ``y = a * re + b * im`` over the last axis.
 
     ``re``/``im``: (..., k) real planes of the rfft half spectrum;
@@ -88,12 +89,12 @@ def post_twiddle(re, im, a, b, interpret: bool = True):
     av = jnp.asarray(a, dtype=re.dtype)
     bv = jnp.asarray(b, dtype=re.dtype)
     y = twiddle_pack(re.reshape(rows, k), im.reshape(rows, k).astype(re.dtype),
-                     av, bv, interpret=interpret)
+                     av, bv)
     return y.reshape(shp)
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def dct2_post_twiddle(fhat_half, interpret: bool = True):
+@jax.jit
+def dct2_post_twiddle(fhat_half):
     """DCT-II from the rfft of the symmetric extension (transforms.dct2
     inner step): y_k = cos_k * re_k + sin_k * im_k over the first M modes."""
     import numpy as np
@@ -101,13 +102,12 @@ def dct2_post_twiddle(fhat_half, interpret: bool = True):
     k = np.arange(m)
     return post_twiddle(fhat_half.real, fhat_half.imag,
                         np.cos(np.pi * k / (2.0 * m)),
-                        np.sin(np.pi * k / (2.0 * m)), interpret=interpret)
+                        np.sin(np.pi * k / (2.0 * m)))
 
 
-@partial(jax.jit, static_argnames=("start", "interpret", "pad_to",
-                                   "max_radix"))
-def rfft_twiddle(x, a, b, start: int = 0, interpret: bool = True,
-                 pad_to: int | None = None, max_radix: int = 4):
+@partial(jax.jit, static_argnames=("start", "pad_to", "max_radix"))
+def rfft_twiddle(x, a, b, start: int = 0, pad_to: int | None = None,
+                 max_radix: int = 4):
     """Fused rfft + r2r post-twiddle: ``a * Re(F)[start:start+k] +
     b * Im(F)[start:start+k]`` of the real (..., N) array ``x`` in ONE
     Pallas kernel (the ``twiddle_pack`` pass runs in the FFT's final-stage
@@ -120,14 +120,12 @@ def rfft_twiddle(x, a, b, start: int = 0, interpret: bool = True,
     im = jnp.zeros_like(re)
     av = jnp.asarray(a, dtype=x.dtype)
     bv = jnp.asarray(b, dtype=x.dtype)
-    y = fft_stockham_twiddle(re, im, av, bv, start=start,
-                             interpret=interpret, pad_to=pad_to,
+    y = fft_stockham_twiddle(re, im, av, bv, start=start, pad_to=pad_to,
                              max_radix=max_radix)
     return y.reshape(shp[:-1] + (av.shape[-1],))
 
 
-def _fft_green(x, green2d, half: bool, interpret: bool, pad_to,
-               max_radix: int = 4):
+def _fft_green(x, green2d, half: bool, pad_to, max_radix: int = 4):
     """Shared body of the fused forward-FFT x Green epilogues."""
     shp = x.shape
     n = shp[-1]
@@ -143,36 +141,33 @@ def _fft_green(x, green2d, half: bool, interpret: bool, pad_to,
     n_fft = pad_to if pad_to is not None else n
     k = n_fft // 2 + 1 if half else n_fft
     g2 = green2d.reshape(-1, k).astype(rdt)
-    orr, oi = fft_stockham_scale(re, im, g2, start=0, interpret=interpret,
-                                 pad_to=pad_to, max_radix=max_radix)
+    orr, oi = fft_stockham_scale(re, im, g2, start=0, pad_to=pad_to,
+                                 max_radix=max_radix)
     return (orr + 1j * oi).reshape(shp[:-1] + (k,)).astype(_cdt(rdt))
 
 
-@partial(jax.jit, static_argnames=("interpret", "pad_to", "max_radix"))
-def fft1d_green(x, green, interpret: bool = True, pad_to: int | None = None,
-                max_radix: int = 4):
+@partial(jax.jit, static_argnames=("pad_to", "max_radix"))
+def fft1d_green(x, green, pad_to: int | None = None, max_radix: int = 4):
     """Fused forward complex FFT x Green multiply: ``FFT(x) * green`` with
     ``green`` real of shape (..., n_fft) broadcast over any leading batch
     of ``x`` -- the last forward direction's ``spectral_scale`` pass runs
     in the FFT's final-stage registers."""
-    return _fft_green(x, green, half=False, interpret=interpret,
-                      pad_to=pad_to, max_radix=max_radix)
+    return _fft_green(x, green, half=False, pad_to=pad_to,
+                      max_radix=max_radix)
 
 
-@partial(jax.jit, static_argnames=("interpret", "pad_to", "max_radix"))
-def rfft_green(x, green, interpret: bool = True, pad_to: int | None = None,
-               max_radix: int = 4):
+@partial(jax.jit, static_argnames=("pad_to", "max_radix"))
+def rfft_green(x, green, pad_to: int | None = None, max_radix: int = 4):
     """Fused rfft x Green multiply on the half spectrum: ``rfft(x) * green``
     with ``green`` real of shape (..., n_fft//2+1); ``pad_to = 2N`` prunes
     the Hockney zero tail inside the same kernel."""
-    return _fft_green(x, green, half=True, interpret=interpret,
-                      pad_to=pad_to, max_radix=max_radix)
+    return _fft_green(x, green, half=True, pad_to=pad_to,
+                      max_radix=max_radix)
 
 
-@partial(jax.jit, static_argnames=("inverse", "interpret", "pad_to",
-                                   "max_radix"))
-def fft1d(x, inverse: bool = False, interpret: bool = True,
-          pad_to: int | None = None, max_radix: int = 4):
+@partial(jax.jit, static_argnames=("inverse", "pad_to", "max_radix"))
+def fft1d(x, inverse: bool = False, pad_to: int | None = None,
+          max_radix: int = 4):
     """Batched complex FFT via the Stockham kernel. x: (..., N) complex.
 
     ``pad_to = 2N`` is the PRUNED Hockney-doubling entry point: the
@@ -183,15 +178,14 @@ def fft1d(x, inverse: bool = False, interpret: bool = True,
     rdt = jnp.float64 if x.dtype == jnp.complex128 else jnp.float32
     re = x.real.reshape(rows, shp[-1]).astype(rdt)
     im = x.imag.reshape(rows, shp[-1]).astype(rdt)
-    orr, oi = fft_stockham(re, im, inverse=inverse, interpret=interpret,
-                           pad_to=pad_to, max_radix=max_radix)
+    orr, oi = fft_stockham(re, im, inverse=inverse, pad_to=pad_to,
+                           max_radix=max_radix)
     n_out = pad_to if pad_to is not None else shp[-1]
     return (orr + 1j * oi).reshape(shp[:-1] + (n_out,)).astype(_cdt(rdt))
 
 
-@partial(jax.jit, static_argnames=("interpret", "pad_to", "max_radix"))
-def rfft_pallas(x, interpret: bool = True, pad_to: int | None = None,
-                max_radix: int = 4):
+@partial(jax.jit, static_argnames=("pad_to", "max_radix"))
+def rfft_pallas(x, pad_to: int | None = None, max_radix: int = 4):
     """rfft of a real (..., N) array via the Stockham kernel: complex FFT
     with a zero imaginary plane, cropped to the half spectrum.  ``pad_to =
     2N`` prunes the Hockney zero tail (length-2N spectrum, N+1 bins kept,
@@ -201,15 +195,14 @@ def rfft_pallas(x, interpret: bool = True, pad_to: int | None = None,
     rows = _rows(shp)
     re = x.reshape(rows, n)
     im = jnp.zeros_like(re)
-    orr, oi = fft_stockham(re, im, interpret=interpret, pad_to=pad_to,
-                           max_radix=max_radix)
+    orr, oi = fft_stockham(re, im, pad_to=pad_to, max_radix=max_radix)
     half = (pad_to if pad_to is not None else n) // 2 + 1
     out = (orr[:, :half] + 1j * oi[:, :half]).astype(_cdt(x.dtype))
     return out.reshape(shp[:-1] + (half,))
 
 
-@partial(jax.jit, static_argnames=("keep", "interpret", "max_radix"))
-def ifft_pruned(y, keep: int, interpret: bool = True, max_radix: int = 4):
+@partial(jax.jit, static_argnames=("keep", "max_radix"))
+def ifft_pruned(y, keep: int, max_radix: int = 4):
     """First ``keep`` samples of the length-2n inverse FFT of ``y`` via the
     parity split: x_j = (ifft_n(Y_even)_j + e^{i pi j / n} ifft_n(Y_odd)_j)
     / 2 for j < n -- two half-length Stockham inverses instead of one
@@ -224,8 +217,7 @@ def ifft_pruned(y, keep: int, interpret: bool = True, max_radix: int = 4):
     halves = []
     for part in (y2[:, 0::2], y2[:, 1::2]):
         orr, oi = fft_stockham(part.real.astype(rdt), part.imag.astype(rdt),
-                               inverse=True, interpret=interpret,
-                               max_radix=max_radix)
+                               inverse=True, max_radix=max_radix)
         halves.append(orr + 1j * oi)
     j = jnp.arange(n, dtype=rdt)
     mod = jnp.exp(1j * jnp.pi * j / n).astype(_cdt(rdt))
@@ -233,9 +225,8 @@ def ifft_pruned(y, keep: int, interpret: bool = True, max_radix: int = 4):
     return out[:, :keep].reshape(shp[:-1] + (keep,)).astype(_cdt(rdt))
 
 
-@partial(jax.jit, static_argnames=("n", "keep", "interpret", "max_radix"))
-def irfft_pruned(y, n: int, keep: int, interpret: bool = True,
-                 max_radix: int = 4):
+@partial(jax.jit, static_argnames=("n", "keep", "max_radix"))
+def irfft_pruned(y, n: int, keep: int, max_radix: int = 4):
     """First ``keep`` samples of the length-``n`` irfft of a hermitian half
     spectrum (..., n//2+1): hermitian extension + parity-split pruned
     inverse, real part."""
@@ -244,14 +235,13 @@ def irfft_pruned(y, n: int, keep: int, interpret: bool = True,
     y2 = y.reshape(rows, shp[-1])
     tail = jnp.conj(y2[:, n - n // 2 - 1:0:-1])
     full = jnp.concatenate([y2, tail], axis=-1)
-    out = ifft_pruned(full, keep, interpret=interpret,
-                      max_radix=max_radix)
+    out = ifft_pruned(full, keep, max_radix=max_radix)
     rdt = jnp.float64 if y.dtype == jnp.complex128 else jnp.float32
     return out.real.reshape(shp[:-1] + (keep,)).astype(rdt)
 
 
-@partial(jax.jit, static_argnames=("n", "interpret", "max_radix"))
-def irfft_pallas(y, n: int, interpret: bool = True, max_radix: int = 4):
+@partial(jax.jit, static_argnames=("n", "max_radix"))
+def irfft_pallas(y, n: int, max_radix: int = 4):
     """irfft of a hermitian half spectrum (..., N//2+1) -> real (..., N)."""
     shp = y.shape
     rows = _rows(shp)
@@ -261,6 +251,5 @@ def irfft_pallas(y, n: int, interpret: bool = True, max_radix: int = 4):
     full = jnp.concatenate([y2, tail], axis=-1)
     rdt = jnp.float64 if y.dtype == jnp.complex128 else jnp.float32
     orr, _ = fft_stockham(full.real.astype(rdt), full.imag.astype(rdt),
-                          inverse=True, interpret=interpret,
-                          max_radix=max_radix)
+                          inverse=True, max_radix=max_radix)
     return orr.reshape(shp[:-1] + (n,)).astype(rdt)

@@ -28,6 +28,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .platform import pallas_call
+
 DEFAULT_BLOCK = (256, 256)
 
 
@@ -44,7 +46,7 @@ def _kernel_batched(re_ref, im_ref, g_ref, out_re_ref, out_im_ref, *, scale):
 
 
 def spectral_scale(re, im, green, scale: float,
-                   block=DEFAULT_BLOCK, interpret=True):
+                   block=DEFAULT_BLOCK):
     """re/im: (rows, lanes) or (B, rows, lanes); green: (rows, lanes).
 
     Returns the scaled (re, im) pair with the input shape; the batched form
@@ -63,13 +65,12 @@ def spectral_scale(re, im, green, scale: float,
         grid = (pl.cdiv(rows, br), pl.cdiv(lanes, bl))
         spec = gspec2d
         body = _kernel
-    fn = pl.pallas_call(
+    fn = pallas_call(
         partial(body, scale=scale),
         grid=grid,
         in_specs=[spec, spec, gspec2d],
         out_specs=[spec, spec],
         out_shape=[jax.ShapeDtypeStruct(re.shape, re.dtype),
                    jax.ShapeDtypeStruct(im.shape, im.dtype)],
-        interpret=interpret,
     )
     return fn(re, im, green)

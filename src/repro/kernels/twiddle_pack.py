@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .platform import pallas_call
+
 DEFAULT_BLOCK = (256, 256)
 
 
@@ -29,7 +31,7 @@ def _kernel(re_ref, im_ref, cos_ref, sin_ref, out_ref):
                     sin_ref[...] * im_ref[...])
 
 
-def twiddle_pack(re, im, cos, sin, block=DEFAULT_BLOCK, interpret=True):
+def twiddle_pack(re, im, cos, sin, block=DEFAULT_BLOCK):
     """re/im: (rows, k); cos/sin: (k,) -> y (rows, k)."""
     rows, k = re.shape
     br = min(block[0], rows)
@@ -37,12 +39,11 @@ def twiddle_pack(re, im, cos, sin, block=DEFAULT_BLOCK, interpret=True):
     grid = (pl.cdiv(rows, br), pl.cdiv(k, bk))
     mat = pl.BlockSpec((br, bk), lambda i, j: (i, j))
     vec = pl.BlockSpec((1, bk), lambda i, j: (0, j))
-    fn = pl.pallas_call(
+    fn = pallas_call(
         _kernel,
         grid=grid,
         in_specs=[mat, mat, vec, vec],
         out_specs=mat,
         out_shape=jax.ShapeDtypeStruct(re.shape, re.dtype),
-        interpret=interpret,
     )
     return fn(re, im, cos.reshape(1, -1), sin.reshape(1, -1))

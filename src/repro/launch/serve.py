@@ -10,7 +10,9 @@ prompts).  Reports per-tenant latency percentiles, server throughput,
 batch occupancy and warm-pool stats; ``--seq`` re-runs the same traffic
 under sequential admission (``max_batch=1``) for the coalescing A/B.
 ``benchmarks/bench_serve.py`` reuses ``run_harness`` for the
-BENCH_serve.json sweep.
+BENCH_serve.json sweep.  On the TPU it serves in f32 (x64 stays off);
+elsewhere the default is f64.  A run in which the degradation ladder
+rescued any solve fails.
 """
 from __future__ import annotations
 
@@ -39,7 +41,8 @@ def tenant_specs(n: int, engine: str = "xla"):
 
 def run_harness(*, n=32, tenants=8, requests=12, max_batch=8,
                 max_delay_ms=4.0, memory_budget_mb=None, workers=1,
-                engine="xla", seed=0, check=True, specs=None) -> dict:
+                engine="xla", seed=0, check=True, specs=None,
+                dtype="float64") -> dict:
     """Drive a fresh server with ``tenants`` concurrent threads, each
     bursting ``requests`` solve requests (open loop -- the heavy-traffic
     regime the server exists for), over mixed plan keys.
@@ -47,15 +50,18 @@ def run_harness(*, n=32, tenants=8, requests=12, max_batch=8,
     Returns the result payload: wall time, throughput, per-tenant
     percentile summaries, server/pool stats, and -- when ``check`` is on
     -- the max deviation vs per-request reference solves (must be 0.0:
-    coalescing and rank padding never perturb a row).
+    coalescing and rank padding never perturb a row).  Raises when the
+    degradation ladder rescued any solve.
     """
+    from repro.launch.solve import require_clean
     from repro.serve import PoissonServer
 
     specs = specs or tenant_specs(n, engine)
     rng = np.random.default_rng(seed)
     traffic = {  # tenant -> (spec, [rhs]) pinned before the clock starts
         f"t{i}": (specs[i % len(specs)],
-                  [rng.standard_normal((n, n, n)) for _ in range(requests)])
+                  [rng.standard_normal((n, n, n)).astype(dtype)
+                   for _ in range(requests)])
         for i in range(tenants)}
 
     server = PoissonServer(max_batch=max_batch, max_delay_ms=max_delay_ms,
@@ -76,7 +82,7 @@ def run_harness(*, n=32, tenants=8, requests=12, max_batch=8,
         # state serving is the regime of interest, not first-compile cost
         for spec in specs:
             for b in server.batch_ranks:
-                fb = [np.zeros((n, n, n)) for _ in range(b)]
+                fb = [np.zeros((n, n, n), dtype) for _ in range(b)]
                 [f.result(timeout=600)
                  for f in [server.submit(x, spec, tenant="_warm")
                            for x in fb]]
@@ -94,6 +100,10 @@ def run_harness(*, n=32, tenants=8, requests=12, max_batch=8,
 
     if errors:
         raise RuntimeError("harness clients failed: " + "; ".join(errors))
+    degraded = {k: v["degradations"] for k, v in tstats.items()
+                if v["degradations"]}
+    if degraded:
+        raise RuntimeError(f"served solves degraded: {degraded}")
 
     total = tenants * requests
     payload = {
@@ -118,6 +128,8 @@ def run_harness(*, n=32, tenants=8, requests=12, max_batch=8,
                 maxdev = max(maxdev, float(np.max(np.abs(
                     np.asarray(ref.solve(f)) - r.u))))
         payload["max_abs_dev_vs_individual"] = maxdev
+    for spec in specs:
+        require_clean(spec.build())
     return payload
 
 
@@ -135,6 +147,9 @@ def main(argv=None):
                     help="warm-pool memory budget (default unbounded)")
     ap.add_argument("--workers", type=int, default=1)
     ap.add_argument("--engine", default="xla", choices=["xla", "pallas"])
+    ap.add_argument("--dtype", default=None, choices=["float32", "float64"],
+                    help="solve precision (default: float32 on the TPU, "
+                         "float64 elsewhere)")
     ap.add_argument("--seq", action="store_true",
                     help="also run the sequential-admission baseline "
                          "(max_batch=1) and report the coalescing speedup")
@@ -145,13 +160,15 @@ def main(argv=None):
                     help="write the full payload to this path")
     args = ap.parse_args(argv)
 
-    import jax
-    jax.config.update("jax_enable_x64", True)
+    from repro.launch.cache import use_compile_cache
+    from repro.launch.solve import solve_dtype
+    use_compile_cache()
+    dtype = solve_dtype(args.dtype)
 
     kw = dict(n=args.n, tenants=args.tenants, requests=args.requests,
               max_delay_ms=args.delay_ms, memory_budget_mb=args.budget_mb,
               workers=args.workers, engine=args.engine,
-              check=not args.no_check)
+              check=not args.no_check, dtype=dtype)
     payload = run_harness(max_batch=args.max_batch, **kw)
     print(f"[serve] {args.tenants} tenants x {args.requests} req, "
           f"n={args.n}^3, max_batch={args.max_batch}: "

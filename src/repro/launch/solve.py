@@ -6,7 +6,11 @@
 
 Builds the pencil-decomposed solver on a (p1, p2) process grid, solves the
 paper's fully-unbounded Gaussian-bump case and reports the error against
-the analytical solution plus per-strategy timing.
+the analytical solution plus per-strategy timing.  On the TPU it solves in
+f32 (x64 stays off); elsewhere the default is f64.  A solve that the
+degradation ladder had to rescue (a degraded engine, comm strategy, layout
+or doubling mode, or a retry) fails the run: the printed ``engine=`` is
+the engine that ran.
 """
 from __future__ import annotations
 
@@ -30,6 +34,9 @@ def main(argv=None):
     ap.add_argument("--green", default="chat2")
     ap.add_argument("--engine", default="xla", choices=["xla", "pallas"],
                     help="transform engine: pure XLA or the Pallas kernels")
+    ap.add_argument("--dtype", default=None, choices=["float32", "float64"],
+                    help="solve precision (default: float32 on the TPU, "
+                         "float64 elsewhere)")
     ap.add_argument("--doubling", default="deferred",
                     choices=["deferred", "upfront"],
                     help="Hockney doubling: deferred (pruned transforms + "
@@ -74,8 +81,12 @@ def main(argv=None):
             f"--xla_force_host_platform_device_count={n_dev}"
 
     import jax
-    jax.config.update("jax_enable_x64", True)
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
+    platform = jax.devices()[0].platform
+    dtype = solve_dtype(args.dtype)
     import jax.numpy as jnp
+    from repro.core.analytic import case_a, case_b
     from repro.core.bc import BCType, DataLayout
     from repro.core.comm import CommConfig
     from repro.core.solver import get_solver, solver_cache_info
@@ -95,7 +106,7 @@ def main(argv=None):
             else CommConfig(strategy=args.comm, n_chunks=args.chunks))
     solver = get_solver(
         (args.n,) * 3, 1.0, bcs, layout=layout, green_kind=args.green,
-        mesh=mesh, comm=comm, dtype=jnp.float64,
+        mesh=mesh, comm=comm, dtype=jnp.dtype(dtype),
         engine=args.engine, doubling=args.doubling,
         relayout=args.relayout, autotune_search=args.search)
     if args.comm == "auto":
@@ -117,9 +128,6 @@ def main(argv=None):
                   "sweep skipped)")
 
     # rhs: the paper's validation field for the chosen BCs
-    import sys
-    sys.path.insert(0, "tests")
-    from test_poisson import case_a, case_b
     rhs, sol = (case_b if args.bcs == "unb" else case_a)(args.n, layout)
     if args.bcs == "per":
         # simple periodic field
@@ -132,6 +140,7 @@ def main(argv=None):
             np.cos(2 * np.pi * z)
         rhs = -(4 + 16 + 4) * np.pi ** 2 * sol
 
+    rhs = rhs.astype(dtype)
     if args.batch > 1:
         rhs = np.broadcast_to(rhs, (args.batch,) + rhs.shape).copy()
 
@@ -146,23 +155,48 @@ def main(argv=None):
         # CFD-driver shape: every step re-acquires the (cached) solver
         solver = get_solver(
             (args.n,) * 3, 1.0, bcs, layout=layout, green_kind=args.green,
-            mesh=mesh, comm=comm, dtype=jnp.float64, engine=args.engine,
-            doubling=args.doubling, relayout=args.relayout,
-            autotune_search=args.search)
+            mesh=mesh, comm=comm, dtype=jnp.dtype(dtype),
+            engine=args.engine, doubling=args.doubling,
+            relayout=args.relayout, autotune_search=args.search)
         u = solver.solve(rhs)
         u.block_until_ready()
     reps = max(args.repeats, args.steps)
     dt = (time.perf_counter() - t0) / reps
-    u0 = np.asarray(u[0] if args.batch > 1 else u)
+    require_clean(solver)
+    u0 = np.asarray(u[0] if args.batch > 1 else u, dtype=np.float64)
     err = float(np.max(np.abs(u0 - sol)))
-    thr = rhs.size * 8 / dt / 1e6 / n_dev
+    thr = rhs.nbytes / dt / 1e6 / n_dev
     ci = solver_cache_info()
     print(f"[solve] n={args.n}^3 grid, ({args.p1}x{args.p2}) pencils, "
-          f"comm={args.comm}, engine={args.engine}, batch={args.batch}: "
+          f"comm={solver.comm.strategy}, engine={solver.engine.name}, "
+          f"dtype={dtype}, batch={args.batch}, on {n_dev} {platform}: "
           f"{dt*1e3:.1f} ms/solve, E_inf={err:.3e}, "
+          f"rel E_inf={err / np.max(np.abs(sol)):.3e}, "
           f"throughput {thr:.1f} MB/s/rank, "
           f"plan-cache {ci['hits']} hits / {ci['misses']} misses")
     return err
+
+
+def solve_dtype(requested=None) -> str:
+    """The solve dtype: ``requested``, else float32 on the TPU and float64
+    elsewhere.  x64 is turned on only for a float64 solve."""
+    import jax
+    dtype = requested or ("float32" if jax.devices()[0].platform == "tpu"
+                          else "float64")
+    if dtype == "float64":
+        jax.config.update("jax_enable_x64", True)
+    return dtype
+
+
+def require_clean(solver):
+    """Raise unless every solve so far ran the configuration asked for:
+    no degradation-ladder rung and no retry.  The ladder is for runs that
+    arm faults; on the main path a rescue would hide a broken engine."""
+    st = solver.stats
+    if st["degradations"] or st.get("retries", 0):
+        raise RuntimeError(
+            f"the solve did not run as configured: {st.get('retries', 0)} "
+            f"retries, degradations {st['degradations']}")
 
 
 def _run_survivable(args, solver, mesh, comm, rhs, sol, bcs, layout):
@@ -227,6 +261,8 @@ def _run_survivable(args, solver, mesh, comm, rhs, sol, bcs, layout):
                 ck.save(args.ckpt, step, acc)
             step += 1
 
+    if plan is None:                 # no faults armed: nothing to rescue
+        require_clean(solver)
     scale = sum(1.0 / (1 + k) for k in range(args.steps))
     acc0 = acc[0] if args.batch > 1 else acc
     err = float(np.max(np.abs(acc0 / scale - sol)))

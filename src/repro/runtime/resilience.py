@@ -99,9 +99,10 @@ def next_rung(cfg: dict):
 
 # substrings marking an execution error as transient (retry-worthy) when it
 # does not carry an explicit ``transient`` attribute -- the runtime-level
-# statuses a TPU fleet surfaces for preemptions and flaky links
-_TRANSIENT_MARKERS = ("RESOURCE_EXHAUSTED", "UNAVAILABLE",
-                      "DEADLINE_EXCEEDED", "ABORTED")
+# statuses a TPU fleet surfaces for preemptions and flaky links.
+# RESOURCE_EXHAUSTED is not among them: on the chip it is an out-of-memory,
+# which the same program on the same device hits again
+_TRANSIENT_MARKERS = ("UNAVAILABLE", "DEADLINE_EXCEEDED", "ABORTED")
 
 
 def is_transient(e: BaseException) -> bool:

@@ -185,9 +185,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core.comm import CommConfig, topology_switch
 
 mesh = jax.make_mesh((2,), ("ax",))
-shard_map = getattr(jax, "shard_map", None)
-if shard_map is None:
-    from jax.experimental.shard_map import shard_map
+shard_map = jax.shard_map
 
 # uninvolved (chunk) axis has PRIME length 7: n_chunks=2 cannot divide it.
 # The seed silently fell back to one monolithic collective; now the axis is

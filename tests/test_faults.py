@@ -110,6 +110,14 @@ def test_transient_retry_then_exhaust():
     assert ei.value.stage == "s" and not ei.value.degradations
 
 
+@pytest.mark.parametrize("status,transient", [
+    ("UNAVAILABLE", True), ("DEADLINE_EXCEEDED", True), ("ABORTED", True),
+    # out of device memory: the same program on the same chip hits it again
+    ("RESOURCE_EXHAUSTED", False), ("INTERNAL", False)])
+def test_runtime_status_transience(status, transient):
+    assert resilience.is_transient(RuntimeError(f"{status}: x")) is transient
+
+
 def _retry_delays(policy, retries=6):
     """Drive run_with_ladder with always-transient failures and capture
     the backoff delays it would have slept."""

@@ -254,6 +254,13 @@ def test_distributed_scheduled_equals_baseline_and_hlo_census():
 
 # -- Pallas fused epilogues vs numpy oracles --------------------------------
 
+def _kernel_launches(jaxpr_text: str) -> int:
+    """Pallas kernel launches in a printed jaxpr: each launch carries a
+    Mosaic and an interpreted lowering (``repro.kernels.platform``), and
+    only the interpreted one is ``interpret=True``."""
+    return jaxpr_text.count("interpret=True")
+
+
 @pytest.mark.parametrize("n,start", [(16, 0), (64, 1), (128, 5)])
 def test_rfft_twiddle_matches_numpy(n, start):
     from repro.kernels import ops
@@ -323,7 +330,7 @@ def test_fused_r2r_matches_unfused_and_scipy():
         # the pallas path must actually be the fused single kernel
         trace = str(jax.make_jaxpr(
             lambda v: fn(v, engine=eng))(jnp.asarray(x)))
-        assert trace.count("pallas_call") == 1, name
+        assert _kernel_launches(trace) == 1, name
 
 
 def test_fwd_last_green_fuses_and_matches_unfused():
@@ -345,7 +352,7 @@ def test_fwd_last_green_fuses_and_matches_unfused():
     np.testing.assert_allclose(fused, unfused, rtol=1e-4, atol=1e-4)
     trace = str(jax.make_jaxpr(
         lambda v: sched.fwd_last_green(v, d, green))(x))
-    assert trace.count("pallas_call") == 1
+    assert _kernel_launches(trace) == 1
 
 
 # -- radix-4 Stockham stages ------------------------------------------------
