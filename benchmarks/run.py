@@ -19,7 +19,7 @@ def main() -> None:
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--only", default=None,
                     help="comma list: convergence,comm,scaling,biot,"
-                         "kernels,roofline,train,batch,solve")
+                         "kernels,train,batch,solve")
     args = ap.parse_args()
     quick = not args.full
 
@@ -34,7 +34,6 @@ def main() -> None:
         "scaling": "bench_scaling",
         "kernels": "bench_kernels",
         "train": "bench_train",
-        "roofline": "bench_roofline",
         "batch": "bench_batch",
         "solve": "bench_solve",
     }

@@ -310,6 +310,11 @@ class CommStrategy:
             return chunk_axis
         return _uninvolved_axis(x.ndim, split_axis, concat_axis)
 
+    def _scope(self):
+        """The named scope of the switch's ops: the fault site's name,
+        ``comm.<strategy>``.  The ``post`` continuation runs outside it."""
+        return jax.named_scope(f"comm.{self.name}")
+
     # -- to be overridden -------------------------------------------------
     def _switch(self, x, axis_name, split_axis, concat_axis,
                 chunk_axis=None):
@@ -327,14 +332,16 @@ class CommStrategy:
         # fault-injection hook: an armed spec for this strategy simulates
         # the collective dying at trace time (chaos suite; no-op otherwise)
         _faults.fail_point(f"comm.{self.name}")
-        # the scheduled relayout rides the switch (pack or unpack side per
-        # ``fold``): one transpose, adjacent to the collective either way
-        x, split_axis, concat_axis, chunk_axis, unpack = self._pack(
-            x, split_axis, concat_axis, chunk_axis, permute)
-        x = self._prepare(x, axis_name, split_axis, valid_extent)
-        y = self._switch(x, axis_name, split_axis, concat_axis,
-                         chunk_axis=chunk_axis)
-        y = self._permute(y, unpack)
+        with self._scope():
+            # the scheduled relayout rides the switch (pack or unpack side
+            # per ``fold``): one transpose, adjacent to the collective
+            # either way
+            x, split_axis, concat_axis, chunk_axis, unpack = self._pack(
+                x, split_axis, concat_axis, chunk_axis, permute)
+            x = self._prepare(x, axis_name, split_axis, valid_extent)
+            y = self._switch(x, axis_name, split_axis, concat_axis,
+                             chunk_axis=chunk_axis)
+            y = self._permute(y, unpack)
         return post(y) if post is not None else y
 
 
@@ -418,31 +425,39 @@ class OverlapStrategy(CommStrategy):
     def stage(self, x, axis_name, split_axis, concat_axis, post=None,
               chunk_axis=None, valid_extent=None, permute=None):
         _faults.fail_point(f"comm.{self.name}")
-        x, split_axis, concat_axis, chunk_axis, unpack = self._pack(
-            x, split_axis, concat_axis, chunk_axis, permute)
-        x = self._prepare(x, axis_name, split_axis, valid_extent)
+        with self._scope():
+            x, split_axis, concat_axis, chunk_axis, unpack = self._pack(
+                x, split_axis, concat_axis, chunk_axis, permute)
+            x = self._prepare(x, axis_name, split_axis, valid_extent)
         if post is None or self.n_chunks <= 1:
-            y = self._switch(x, axis_name, split_axis, concat_axis,
-                             chunk_axis=chunk_axis)
-            y = self._permute(y, unpack)
+            with self._scope():
+                y = self._switch(x, axis_name, split_axis, concat_axis,
+                                 chunk_axis=chunk_axis)
+                y = self._permute(y, unpack)
             return post(y) if post is not None else y
         ax = self._chunk_axis(x, split_axis, concat_axis, chunk_axis)
         # under fold="unpack" each chunk is permuted as it lands (in the
         # gap its successor's collective is in flight) and the concat axis
         # rides the same permutation into the post frame
         ax_out = ax if unpack is None else unpack.index(ax)
-        chunks, ln = _split_chunks(x, ax, self.n_chunks)
         outs = []
-        inflight = self._collective(chunks[0], axis_name, split_axis,
-                                    concat_axis)
+        with self._scope():
+            chunks, ln = _split_chunks(x, ax, self.n_chunks)
+            inflight = self._collective(chunks[0], axis_name, split_axis,
+                                        concat_axis)
         for k in range(1, self.n_chunks):
-            nxt = self._collective(chunks[k], axis_name, split_axis,
-                                   concat_axis)
+            with self._scope():
+                nxt = self._collective(chunks[k], axis_name, split_axis,
+                                       concat_axis)
+                landed = self._permute(inflight, unpack)
             # overlaps chunk k's wire time
-            outs.append(post(self._permute(inflight, unpack)))
+            outs.append(post(landed))
             inflight = nxt
-        outs.append(post(self._permute(inflight, unpack)))
-        return crop_axis(jnp.concatenate(outs, axis=ax_out), ax_out, ln)
+        with self._scope():
+            landed = self._permute(inflight, unpack)
+        outs.append(post(landed))
+        with self._scope():
+            return crop_axis(jnp.concatenate(outs, axis=ax_out), ax_out, ln)
 
 
 _STRATEGY_CLASSES = {

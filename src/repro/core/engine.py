@@ -16,7 +16,10 @@ multiply -> inverse transforms; this module decides HOW each stage executes:
 
 Every stage runs under a ``jax.named_scope`` named after its fault site
 (``fwd.<d>``, ``bwd.<d>``, ``green``); ``stage_map`` reads off a traced
-solve which stages run a Pallas kernel and which run XLA.
+solve which stages run a Pallas kernel and which run XLA.  Between the
+stages, the row-major pins run under ``pin``, the edge adapters under
+``relayout`` and the topology switches under ``comm.<strategy>``
+(``repro.core.comm``).
 
 A plan is compiled once into a ``TransformSchedule``: per-direction twiddle
 tables (plan-time numpy constants handed to the kernels) plus the combined
@@ -324,7 +327,8 @@ def relayout(x, src, dst):
         return x
     off = x.ndim - len(src)
     axes = tuple(range(off)) + tuple(off + src.index(d) for d in dst)
-    return jnp.transpose(x, axes)
+    with jax.named_scope("relayout"):
+        return jnp.transpose(x, axes)
 
 
 def materialize_doubling(x, dirs):
@@ -362,10 +366,14 @@ def pin_row_major(x):
     where the scheme gives 1.3e-4); with row-major stage outputs the same
     program is exact to f32, for about 6% more time per solve.  The TPU's
     layout constraint takes no complex operand, so a complex array is
-    pinned plane by plane."""
-    if jnp.iscomplexobj(x):
-        return jax.lax.complex(pin_row_major(x.real), pin_row_major(x.imag))
-    return with_layout_constraint(x, Layout(tuple(range(x.ndim))))
+    pinned plane by plane.  The ops run under the scope ``pin``."""
+    def pin(a):
+        return with_layout_constraint(a, Layout(tuple(range(a.ndim))))
+
+    with jax.named_scope("pin"):
+        if jnp.iscomplexobj(x):
+            return jax.lax.complex(pin(x.real), pin(x.imag))
+        return pin(x)
 
 
 def _pinned(stage):

@@ -31,6 +31,7 @@ from .bc import (BCType, DataLayout, DirBC, TransformKind, r2r_kind,
                  INVERSE_KIND)
 from . import transforms as tr
 from . import green as gr
+from repro.runtime import spans
 from .engine import (RELAYOUT_MODES, as_engine, build_schedule,
                      folded_normfact, fwd_1d, bwd_1d, relayout as _relayout,
                      schedule_layouts)
@@ -463,22 +464,25 @@ class PoissonSolver:
         cache entry."""
         b = self._base
         self._cfg = dict(cfg)
-        self.plan = make_plan(b["shape"], b["L"], b["bcs"], b["layout"],
-                              b["green_kind"], b["eps_factor"],
-                              doubling=cfg["doubling"],
-                              order_policy=b["order_policy"])
-        self.engine = as_engine(cfg["engine"])
-        self.schedule = build_schedule(self.plan, self.engine)
+        with spans.span("flups.plan"):
+            self.plan = make_plan(b["shape"], b["L"], b["bcs"], b["layout"],
+                                  b["green_kind"], b["eps_factor"],
+                                  doubling=cfg["doubling"],
+                                  order_policy=b["order_policy"])
+            self.engine = as_engine(cfg["engine"])
+            self.schedule = build_schedule(self.plan, self.engine)
         self.relayout = cfg["relayout"]
         # ONE Green copy, held in the layout the selected pipeline
         # multiplies in: natural for baseline, the spectral LAYOUT (active
         # axis of the last forward stage minor-most) for scheduled --
         # permuted once, at plan time
-        g = build_green(self.plan)
+        with spans.span("flups.green.build"):
+            g = build_green(self.plan)
         self._green_nat = g          # natural layout: health diagnosis
         if self.relayout == "scheduled":
-            g = np.ascontiguousarray(
-                np.transpose(g, self.schedule.layouts.spectral))
+            with spans.span("flups.green.layout"):
+                g = np.ascontiguousarray(
+                    np.transpose(g, self.schedule.layouts.spectral))
         self._green = g
         # jit wrappers are keyed by the active fault-plan token: arming a
         # FaultPlan forces a retrace (the taint/fail_point hooks run at
@@ -667,6 +671,10 @@ class PoissonSolver:
         the corruption persists.  ``"abft-stages"`` runs the checked
         pipeline unconditionally (per-stage sandwiches with inline
         selective recompute -- the chaos net's mode)."""
+        with spans.span("flups.solve", closes_step=True):
+            return self._run_solve(f, verify)
+
+    def _run_solve(self, f, verify):
         from repro.runtime import abft, faults, health, resilience
         f = jnp.asarray(f)
         grid = self.input_shape
@@ -703,7 +711,8 @@ class PoissonSolver:
                     "mismatch": float(m), "tol": float(tol),
                     "action": "localize", "describe": "solve"})
                 return checked()
-            u = self._jitted()(f)
+            with spans.span("flups.launch"):
+                u = self._jitted()(f)
             if verify:
                 health.check_solution(
                     u, f, self.plan, mode=verify, rtol=self.verify_rtol,
@@ -769,6 +778,22 @@ def _freeze(v):
         return repr(v)
 
 
+def _build_solver(shape, L, bcs, layout, green_kind, eps_factor, engine,
+                  doubling, relayout, order_policy, mesh, kw):
+    if mesh is not None:
+        from repro.distributed.pencil import DistributedPoissonSolver
+        return DistributedPoissonSolver(shape, L, bcs, layout, green_kind,
+                                        mesh=mesh, eps_factor=eps_factor,
+                                        engine=engine, doubling=doubling,
+                                        relayout=relayout,
+                                        order_policy=order_policy, **kw)
+    assert set(kw) <= {"verify", "verify_rtol", "abft_rtol"}, \
+        f"unexpected single-process solver kwargs: {kw}"
+    return PoissonSolver(shape, L, bcs, layout, green_kind, eps_factor,
+                         engine=engine, doubling=doubling,
+                         relayout=relayout, order_policy=order_policy, **kw)
+
+
 def get_solver(shape, L, bcs, layout=DataLayout.CELL,
                green_kind=gr.GreenKind.CHAT2, eps_factor=2.0,
                engine="xla", doubling="deferred", relayout="scheduled",
@@ -789,68 +814,63 @@ def get_solver(shape, L, bcs, layout=DataLayout.CELL,
     re-raises in every parked caller and leaves no cache entry behind, so
     the next request retries cleanly.
     """
-    from repro.runtime import faults
-    key = ("dist" if mesh is not None else "single",
-           _freeze(shape), _freeze(L), _freeze(bcs), _freeze(layout),
-           _freeze(green_kind), float(eps_factor),
-           as_engine(engine), str(doubling), str(relayout),
-           str(order_policy), _freeze(mesh), _freeze(kw),
-           # solvers traced under an armed fault plan must never be served
-           # to fault-free callers (their jit cache may carry the fault)
-           ("faults", faults.plan_token()))
-    builder = False
-    with _SOLVER_CACHE_LOCK:
-        s = _SOLVER_CACHE.get(key)
-        if s is not None:
-            _SOLVER_CACHE.move_to_end(key)
-            _SOLVER_CACHE_STATS["hits"] += 1
-            return s
-        build = _SOLVER_BUILDS.get(key)
-        if build is None:
-            build = _SOLVER_BUILDS[key] = _SolverBuild()
-            _SOLVER_CACHE_STATS["misses"] += 1
-            builder = True
-        else:
-            # another thread is already constructing this key: park on its
-            # build instead of duplicating the plan/autotune/jit work
-            _SOLVER_CACHE_STATS["coalesced"] += 1
-    if not builder:
-        build.done.wait()
-        if build.exc is not None:
-            raise build.exc
-        return build.result
-    try:
-        if mesh is not None:
-            from repro.distributed.pencil import DistributedPoissonSolver
-            s = DistributedPoissonSolver(shape, L, bcs, layout, green_kind,
-                                         mesh=mesh, eps_factor=eps_factor,
-                                         engine=engine, doubling=doubling,
-                                         relayout=relayout,
-                                         order_policy=order_policy, **kw)
-        else:
-            assert set(kw) <= {"verify", "verify_rtol", "abft_rtol"}, \
-                f"unexpected single-process solver kwargs: {kw}"
-            s = PoissonSolver(shape, L, bcs, layout, green_kind, eps_factor,
-                              engine=engine, doubling=doubling,
-                              relayout=relayout, order_policy=order_policy,
-                              **kw)
-    except BaseException as e:
+    with spans.span("flups.get_solver") as sp:
+        from repro.runtime import faults
+        key = ("dist" if mesh is not None else "single",
+               _freeze(shape), _freeze(L), _freeze(bcs), _freeze(layout),
+               _freeze(green_kind), float(eps_factor),
+               as_engine(engine), str(doubling), str(relayout),
+               str(order_policy), _freeze(mesh), _freeze(kw),
+               # solvers traced under an armed fault plan must never be
+               # served to fault-free callers (their jit cache may carry
+               # the fault)
+               ("faults", faults.plan_token()))
+        builder = False
         with _SOLVER_CACHE_LOCK:
+            s = _SOLVER_CACHE.get(key)
+            if s is not None:
+                _SOLVER_CACHE.move_to_end(key)
+                _SOLVER_CACHE_STATS["hits"] += 1
+                sp.set("hit", True)
+                return s
+            build = _SOLVER_BUILDS.get(key)
+            if build is None:
+                build = _SOLVER_BUILDS[key] = _SolverBuild()
+                _SOLVER_CACHE_STATS["misses"] += 1
+                builder = True
+            else:
+                # another thread is already constructing this key: park on
+                # its build instead of duplicating the plan/autotune/jit work
+                _SOLVER_CACHE_STATS["coalesced"] += 1
+        if not builder:
+            sp.set("hit", False)
+            build.done.wait()
+            if build.exc is not None:
+                raise build.exc
+            return build.result
+        sp.set("hit", False)
+        try:
+            with spans.span("flups.build"):
+                s = _build_solver(shape, L, bcs, layout, green_kind,
+                                  eps_factor, engine, doubling, relayout,
+                                  order_policy, mesh, kw)
+        except BaseException as e:
+            with _SOLVER_CACHE_LOCK:
+                _SOLVER_BUILDS.pop(key, None)
+                _SOLVER_CACHE_STATS["build_failures"] += 1
+            build.exc = e
+            build.done.set()
+            raise
+        with _SOLVER_CACHE_LOCK:
+            _SOLVER_CACHE[key] = s
+            _SOLVER_CACHE.move_to_end(key)
+            while len(_SOLVER_CACHE) > _SOLVER_CACHE_CAPACITY:
+                _SOLVER_CACHE.popitem(last=False)
+                _SOLVER_CACHE_STATS["evictions"] += 1
             _SOLVER_BUILDS.pop(key, None)
-            _SOLVER_CACHE_STATS["build_failures"] += 1
-        build.exc = e
+        build.result = s
         build.done.set()
-        raise
-    with _SOLVER_CACHE_LOCK:
-        _SOLVER_CACHE[key] = s
-        _SOLVER_CACHE.move_to_end(key)
-        while len(_SOLVER_CACHE) > _SOLVER_CACHE_CAPACITY:
-            _SOLVER_CACHE.popitem(last=False)
-            _SOLVER_CACHE_STATS["evictions"] += 1
-        _SOLVER_BUILDS.pop(key, None)
-    build.result = s
-    build.done.set()
-    return s
+        return s
 
 
 def clear_solver_cache():

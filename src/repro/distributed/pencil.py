@@ -58,6 +58,7 @@ from repro.core.comm import (CommConfig, as_comm, autotune_comm,
 from repro.core.engine import (RELAYOUT_MODES, as_engine, build_schedule,
                                relayout)
 from repro.core.solver import make_plan, build_green
+from repro.runtime import spans
 
 __all__ = ["DistributedPoissonSolver"]
 
@@ -177,16 +178,18 @@ class DistributedPoissonSolver:
         lazy_green, dtype = c["lazy_green"], c["dtype"]
         axes, mesh = self.axes, self.mesh
         self._cfg = dict(cfg)
-        self.plan = make_plan(shape, L, bcs, layout, green_kind, eps_factor,
-                              doubling=cfg["doubling"],
-                              order_policy=order_policy)
-        # keep the constructor's engine OBJECT (it may carry a non-default
-        # max_radix) as long as the ladder has not degraded the engine name
-        base_eng = c.get("engine_obj")
-        self.engine = (base_eng if base_eng is not None
-                       and base_eng.name == cfg["engine"]
-                       else self._engine(cfg["engine"]))
-        self.schedule = build_schedule(self.plan, self.engine)
+        with spans.span("flups.plan"):
+            self.plan = make_plan(shape, L, bcs, layout, green_kind,
+                                  eps_factor, doubling=cfg["doubling"],
+                                  order_policy=order_policy)
+            # keep the constructor's engine OBJECT (it may carry a
+            # non-default max_radix) as long as the ladder has not degraded
+            # the engine name
+            base_eng = c.get("engine_obj")
+            self.engine = (base_eng if base_eng is not None
+                           and base_eng.name == cfg["engine"]
+                           else self._engine(cfg["engine"]))
+            self.schedule = build_schedule(self.plan, self.engine)
         self.relayout = cfg["relayout"]
         e = self.plan.order
         d0, d1, d2 = e
@@ -220,11 +223,14 @@ class DistributedPoissonSolver:
                 tuple(gshape[d] for d in gperm), gdtype)
         else:
             if self._green_raw is None:
-                self._green_raw = build_green(self.plan)
-            g = self._green_raw.astype(gdtype)
-            gp = np.zeros(gshape, dtype=gdtype)
-            gp[tuple(slice(0, s) for s in g.shape)] = g
-            self._green_np = np.ascontiguousarray(np.transpose(gp, gperm))
+                with spans.span("flups.green.build"):
+                    self._green_raw = build_green(self.plan)
+            with spans.span("flups.green.layout"):
+                g = self._green_raw.astype(gdtype)
+                gp = np.zeros(gshape, dtype=gdtype)
+                gp[tuple(slice(0, s) for s in g.shape)] = g
+                self._green_np = np.ascontiguousarray(
+                    np.transpose(gp, gperm))
 
         spec_in = [None, None, None]
         spec_in[d1], spec_in[d2] = axes[0], axes[1]
@@ -689,9 +695,10 @@ class DistributedPoissonSolver:
 
     def green_device(self):
         if self._green_dev is None:
-            self._green_dev = jax.device_put(
-                self._green_np,
-                NamedSharding(self.mesh, self.g_spec))
+            with spans.span("flups.green.put"):
+                self._green_dev = jax.device_put(
+                    self._green_np,
+                    NamedSharding(self.mesh, self.g_spec))
         return self._green_dev
 
     def _dispatch(self, f, local_batch: bool, abft: bool = False,
@@ -704,27 +711,30 @@ class DistributedPoissonSolver:
         under ``lite`` the SAME jit as verify-off runs (the sandwich is
         entirely host-side) and ``(u, qs, w_valid, w_norm)`` returns (or
         None when the sandwich is unavailable for this config)."""
-        fp = self._pad_input(f)
-        spec = self.input_spec(local_batch)
-        fp = jax.device_put(fp, NamedSharding(self.mesh, spec))
+        with spans.span("flups.pad"):
+            fp = self._pad_input(f)
+            spec = self.input_spec(local_batch)
+            fp = jax.device_put(fp, NamedSharding(self.mesh, spec))
         names = rep = None
         if lite:
             ent = self._lite_pair(fp.shape, local_batch)
             if ent is None:
                 return None
             qs, wv, wn = ent
-            out = self.jit_for(local_batch)(fp, self.green_device())
-        elif abft:
-            fn, names = self.abft_jit_for(local_batch)
-            out, rep = fn(fp, self.green_device())
-        else:
-            out = self.jit_for(local_batch)(fp, self.green_device())
+        green = self.green_device()
+        with spans.span("flups.launch"):
+            if abft:
+                fn, names = self.abft_jit_for(local_batch)
+                out, rep = fn(fp, green)
+            else:
+                out = self.jit_for(local_batch)(fp, green)
         from repro.core.engine import crop_doubling
-        d0, d1, d2 = self.plan.order
-        off = out.ndim - 3
-        out = _crop_dim(out, d1 + off, self._U[d1])
-        out = _crop_dim(out, d2 + off, self._U[d2])
-        out = crop_doubling(out, self.plan.dirs)
+        with spans.span("flups.crop"):
+            d0, d1, d2 = self.plan.order
+            off = out.ndim - 3
+            out = _crop_dim(out, d1 + off, self._U[d1])
+            out = _crop_dim(out, d2 + off, self._U[d2])
+            out = crop_doubling(out, self.plan.dirs)
         if lite:
             return (out,) + ent
         return (out, names, rep) if abft else out
@@ -771,6 +781,10 @@ class DistributedPoissonSolver:
         ``stats["integrity"]``, and wire-attributed corruption retries as
         a transient before degrading.
         """
+        with spans.span("flups.solve", closes_step=True):
+            return self._run_solve(f, verify)
+
+    def _run_solve(self, f, verify):
         from repro.runtime import abft as _abft
         from repro.runtime import faults, health, resilience
         f_host = f if (isinstance(f, np.ndarray)

@@ -184,8 +184,8 @@ def _build_poisson_cell(shape_name, mesh, comm):
     # solve (in-block batch axis); multi-pod shards the batch over "pod"
     local_batch = not multi and CONFIG.batch > 1
     batch = CONFIG.batch if (multi or local_batch) else None
-    # the global plan cache makes cell re-construction (reprobe sweeps,
-    # repeated dryruns over the same mesh) hit one live solver instance
+    # the global plan cache makes cell re-construction (repeated dryruns
+    # over the same mesh) hit one live solver instance
     solver = get_solver(
         (CONFIG.n,) * 3, 1.0, CONFIG.bcs, layout=CONFIG.layout,
         green_kind=CONFIG.green, mesh=mesh,

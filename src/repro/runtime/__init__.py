@@ -6,8 +6,9 @@
 ``abft``        algorithm-based fault tolerance: per-stage checksum
                 invariants, wire sidecars, localize-and-recompute
                 (DESIGN.md #13)
+``spans``       host spans at the solver's layer boundaries, off by default
 """
-from . import abft, faults, health, resilience  # noqa: F401
+from . import abft, faults, health, resilience, spans  # noqa: F401
 
 from .abft import IntegrityError  # noqa: F401
 from .resilience import SolveError  # noqa: F401

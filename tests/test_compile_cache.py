@@ -1,7 +1,9 @@
-"""Where the entry points put JAX's persistent compilation cache.
+"""Where the entry points put JAX's persistent compilation cache, and
+what keys it.
 
 ``$JAX_COMPILATION_CACHE_DIR`` wins and nothing sets another directory;
-without it the cache is the fixed ``<checkout>/.jax_cache``.  Checked in
+without it the cache is the fixed ``<checkout>/.jax_cache``.  Programs
+that differ only in their op names get entries of their own.  Checked in
 a child process, which starts with a fresh JAX config.
 """
 import os
@@ -47,3 +49,37 @@ def test_default_is_the_checkout_cache():
     env.pop("JAX_COMPILATION_CACHE_DIR", None)
     where, config = _run(env)
     assert where == config == str(CHECKOUT_CACHE)
+
+
+_SCOPES = r"""
+import os
+import jax, jax.numpy as jnp
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+from repro.launch.cache import use_compile_cache
+where = use_compile_cache()
+
+
+def scoped(name):
+    def f(x):
+        with jax.named_scope(name):
+            return jnp.sin(x) * 3.0 + 1.0
+    return f
+
+
+for name in ("pin", "relayout", "pin"):
+    jax.jit(scoped(name))(jnp.arange(17.0)).block_until_ready()
+print(sum(e.startswith("jit_f-") for e in os.listdir(where)))
+"""
+
+
+def test_op_names_key_the_cache(tmp_path):
+    """A cached executable carries the op names it was compiled with, and
+    the profiler reports those: the same program under another scope must
+    compile anew, the same scope again must hit."""
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               PYTHONPATH="src", JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, "-c", _SCOPES], cwd=ROOT, env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.split() == ["2"]
