@@ -4,7 +4,12 @@ The paper's pipeline is (per direction) 1-D transform -> pointwise Green
 multiply -> inverse transforms; this module decides HOW each stage executes:
 
   engine="xla"     pure jnp/XLA ops (rfft/irfft half-spectrum transforms,
-                   fused elementwise) -- the default everywhere.
+                   fused elementwise) -- the default everywhere.  On the
+                   TPU the DFT directions of the lengths in
+                   ``transforms.MXU_DFT_LENGTHS`` run as float32 products
+                   against plan-time DFT matrices cut to the live rows
+                   and columns (``TransformEngine.mxu_dft``, under the
+                   scope ``mxu_dft``) instead of XLA's FFT.
   engine="pallas"  the hand-written TPU kernels take over the hot loops:
                    ``twiddle_pack`` for the r2r post-twiddle,
                    ``fft_stockham`` for the (r)FFT lengths it compiles for
@@ -16,10 +21,10 @@ multiply -> inverse transforms; this module decides HOW each stage executes:
 
 Every stage runs under a ``jax.named_scope`` named after its fault site
 (``fwd.<d>``, ``bwd.<d>``, ``green``); ``stage_map`` reads off a traced
-solve which stages run a Pallas kernel and which run XLA.  Between the
-stages, the row-major pins run under ``pin``, the edge adapters under
-``relayout`` and the topology switches under ``comm.<strategy>``
-(``repro.core.comm``).
+solve which stages run a Pallas kernel, DFT-matrix products or XLA's
+FFT.  Between the stages, the row-major pins run under ``pin``, the edge
+adapters under ``relayout`` and the topology switches under
+``comm.<strategy>`` (``repro.core.comm``).
 
 A plan is compiled once into a ``TransformSchedule``: per-direction twiddle
 tables (plan-time numpy constants handed to the kernels) plus the combined
@@ -77,6 +82,10 @@ RELAYOUT_MODES = ("scheduled", "baseline")
 ENGINES = ("xla", "pallas")
 
 
+# the data types of the MXU route: the float32 path (float64 keeps jnp.fft)
+_MXU_DTYPES = (jnp.dtype(jnp.float32), jnp.dtype(jnp.complex64))
+
+
 @dataclass(frozen=True)
 class TransformEngine:
     """Execution backend selection for the transform + pointwise stages.
@@ -113,6 +122,18 @@ class TransformEngine:
         plan-time routing rule); False on the XLA engine."""
         from repro.kernels.fft_stockham import fits
         return self.use_pallas and fits(n, self.platform)
+
+    def mxu_dft(self, n: int, dtype) -> bool:
+        """Whether a length-``n`` DFT of ``dtype`` data runs as products
+        against plan-time DFT matrices on the MXU (the plan-time routing
+        rule of the DFT directions): on the TPU, in float32/complex64, for
+        the lengths of ``transforms.MXU_DFT_LENGTHS`` the Stockham kernel
+        does not take.  Everything else runs ``jnp.fft``."""
+        from .transforms import MXU_DFT_LENGTHS
+        return (self.platform == "tpu"
+                and jnp.dtype(dtype) in _MXU_DTYPES
+                and not self.kernel_fft(n)
+                and n in MXU_DFT_LENGTHS)
 
 
 def as_engine(engine) -> TransformEngine:
@@ -155,6 +176,17 @@ def on_last_axis(x, axis, fn):
     return jnp.moveaxis(y, -1, axis)
 
 
+def _dft_mats(sched, p, x):
+    """The plan-time DFT matrices of direction ``p`` when its transform of
+    ``x`` runs as MXU products (``TransformEngine.mxu_dft``), else None."""
+    if sched is None or not sched.dft_mats:
+        return None
+    mats = sched.dft_mats[p.dim]
+    if mats is None or not sched.engine.mxu_dft(p.n_fft, x.dtype):
+        return None
+    return mats
+
+
 def _fwd_last(x, p, sched=None):
     """Forward 1-D transform of direction ``p`` applied to the LAST axis
     of ``x`` (the layout-scheduled hot path: the caller guarantees the
@@ -170,22 +202,27 @@ def _fwd_last(x, p, sched=None):
         x = _faults.taint(f"fwd.{p.dim}", x)
         if engine is not None and engine.use_pallas:
             _faults.fail_point(f"pallas.fwd.{p.dim}")
-        if p.pre_padded:
-            # dense up-front doubling: the zero extension is already in the
-            # array, the transform is a plain full-length one
-            if p.category in ("sym", "semi"):
-                raise AssertionError("pre_padded is a DFT-direction mode")
-            return (tr._rfft(x, engine) if p.dft == "r2c"
-                    else tr._cfft(x, engine))
-        if p.flip:
-            x = x[..., ::-1]
-        x = x[..., p.in_start:p.in_start + p.n_in]
+        if p.pre_padded and p.category in ("sym", "semi"):
+            raise AssertionError("pre_padded is a DFT-direction mode")
+        if not p.pre_padded:
+            if p.flip:
+                x = x[..., ::-1]
+            x = x[..., p.in_start:p.in_start + p.n_in]
         if p.category in ("sym", "semi"):
             if p.n_fft > p.n_in:
                 pad = [(0, 0)] * (x.ndim - 1) + [(0, p.n_fft - p.n_in)]
                 x = jnp.pad(x, pad)
             tables = sched.fwd_tables[p.dim] if sched is not None else None
             return tr.r2r_forward(x, p.kind, engine=engine, tables=tables)
+        mats = _dft_mats(sched, p, x)
+        if mats is not None:
+            with jax.named_scope("mxu_dft"):
+                return tr.mxu_dft_forward(x, mats)
+        if p.pre_padded:
+            # dense up-front doubling: the zero extension is already in the
+            # array, the transform is a plain full-length one
+            return (tr._rfft(x, engine) if p.dft == "r2c"
+                    else tr._cfft(x, engine))
         if p.dft == "r2c":
             # pruned forward: the length-n_fft spectrum from the n_in nonzero
             # inputs (Pallas skips the zero tail; XLA pads -- bit-identical)
@@ -210,6 +247,11 @@ def _bwd_last(y, p, sched=None):
             tables = sched.bwd_tables[p.dim] if sched is not None else None
             x = tr.r2r_backward(y, p.kind, engine=engine, tables=tables)
             x = x[..., :p.n_in]
+        elif (mats := _dft_mats(sched, p, y)) is not None:
+            with jax.named_scope("mxu_dft"):
+                x = tr.mxu_dft_backward(y, mats)
+            if p.pre_padded:
+                return x
         elif p.pre_padded:
             # dense mode keeps the doubled extent; cropped once at solve end
             return (tr._irfft(y, p.n_fft, engine) if p.dft == "r2c"
@@ -396,6 +438,9 @@ class TransformSchedule:
     dirs: tuple = ()     # per logical dim: the plan's Plan1D
     order: tuple = ()    # the plan's forward execution order
     layouts: LayoutSchedule = None   # per-stage axis permutations
+    # per logical dim: the DFT matrices of a direction on the MXU route
+    # (``transforms.dft_matrices``), else None
+    dft_mats: tuple = ()
 
     # -- fused transform+switch stage API (chunk-safe by construction) -----
     #
@@ -510,14 +555,17 @@ _STAGE = re.compile(r"(?:^|/)((?:fwd|bwd)\.\d(?:\+green)?|green)(?=/|$)")
 
 
 def stage_map(jaxpr) -> dict:
-    """Which stages of a traced solve run a Pallas kernel and which XLA.
+    """Which stages of a traced solve run a Pallas kernel, DFT-matrix
+    products or XLA's FFT.
 
     ``jaxpr`` is the ``jax.make_jaxpr`` of a solve.  Every equation inside
     a stage's named scope counts: a ``pallas_call`` marks the stage
-    "pallas", an XLA ``fft`` marks it "xla" (a stage with both, e.g. a
-    kernel FFT beside an XLA FFT of a routed length, is "pallas+xla"; a
-    stage with neither -- the XLA engine's Green multiply -- is "xla").
-    Returns ``{stage: kind}`` in the order the stages were traced."""
+    "pallas", a ``dot_general`` under the ``mxu_dft`` scope (the MXU route,
+    ``TransformEngine.mxu_dft``) marks it "mxu", an XLA ``fft`` marks it
+    "xla" (a stage with two, e.g. a kernel FFT beside an XLA FFT of a
+    routed length, is "pallas+xla"; a stage with none -- the XLA engine's
+    Green multiply -- is "xla").  Returns ``{stage: kind}`` in the order
+    the stages were traced."""
     found: dict = {}
 
     def walk(jx, prefix):
@@ -532,6 +580,9 @@ def stage_map(jaxpr) -> dict:
                 continue                  # the kernel body is not a stage
             if name == "fft" and kinds is not None:
                 kinds.add("xla")
+            if (name == "dot_general" and kinds is not None
+                    and "mxu_dft" in stack.split("/")):
+                kinds.add("mxu")
             for v in eqn.params.values():
                 for sub in (v if isinstance(v, (tuple, list)) else (v,)):
                     sub = getattr(sub, "jaxpr", sub)
@@ -558,14 +609,21 @@ def build_schedule(plan, engine=None) -> TransformSchedule:
     from .bc import INVERSE_KIND
 
     engine = as_engine(engine)
-    fwd, bwd = [], []
+    fwd, bwd, mats = [], [], []
     for p in plan.dirs:
         if p.kind is None:       # DFT direction: no r2r twiddles
             fwd.append(None)
             bwd.append(None)
+            # the spectra of the float32 path are complex64
+            routed = engine.mxu_dft(p.n_fft, jnp.complex64)
+            mats.append(tr.dft_matrices(
+                p.dft, p.n_fft, p.n_fft if p.pre_padded else p.n_in)
+                if routed else None)
         else:
             fwd.append(tr.twiddle_tables(p.kind, p.n_fft))
             bwd.append(tr.twiddle_tables(INVERSE_KIND[p.kind], p.n_fft))
+            mats.append(None)
     return TransformSchedule(engine, tuple(fwd), tuple(bwd),
                              folded_normfact(plan), plan.dirs, plan.order,
-                             schedule_layouts(plan.order, len(plan.dirs)))
+                             schedule_layouts(plan.order, len(plan.dirs)),
+                             tuple(mats))

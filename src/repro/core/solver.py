@@ -453,7 +453,8 @@ class PoissonSolver:
         self.abft_rtol = float(abft_rtol)
         self.stats = {"solves": 0, "retries": 0, "verify_failures": 0,
                       "degradations": []}
-        self._configure({"engine": as_engine(engine).name,
+        self._engine_obj = as_engine(engine)
+        self._configure({"engine": self._engine_obj.name,
                          "doubling": doubling, "relayout": relayout})
 
     def _configure(self, cfg: dict):
@@ -469,7 +470,11 @@ class PoissonSolver:
                                   b["green_kind"], b["eps_factor"],
                                   doubling=cfg["doubling"],
                                   order_policy=b["order_policy"])
-            self.engine = as_engine(cfg["engine"])
+            # keep the constructor's engine object (its max_radix and
+            # platform) as long as the ladder has not degraded the name
+            self.engine = (self._engine_obj
+                           if self._engine_obj.name == cfg["engine"]
+                           else as_engine(cfg["engine"]))
             self.schedule = build_schedule(self.plan, self.engine)
         self.relayout = cfg["relayout"]
         # ONE Green copy, held in the layout the selected pipeline

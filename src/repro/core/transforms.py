@@ -27,12 +27,21 @@ the ``fft_stockham`` kernel takes on the engine's platform
 lengths the forward post-twiddle kinds (dct1/dct2/dst2) run the FUSED
 ``rfft_twiddle`` kernel instead -- the twiddle executes in the FFT's
 final-stage registers, one HBM round trip instead of three (DESIGN.md #9).
+
+The DFT directions' MXU route (``mxu_dft_forward``/``mxu_dft_backward``):
+on the TPU, a float32 DFT of a length in ``MXU_DFT_LENGTHS`` runs as
+products against plan-time DFT matrices (``dft_matrices``, held by the
+plan's ``TransformSchedule``) at ``Precision.HIGHEST`` instead of
+``jnp.fft``.  The matrices are cut to the live rows and columns, so the
+pruned forward reads only the live inputs and the pruned inverse writes
+only the kept outputs; the routing rule is ``TransformEngine.mxu_dft``.
 """
 from __future__ import annotations
 
 from functools import lru_cache
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from .bc import TransformKind
@@ -62,7 +71,6 @@ def _scan_dtype(dtype):
     roundoff accumulates linearly along the axis, so run them in f64 when
     x64 is enabled (and stay put otherwise -- requesting f64 under
     disabled x64 would only emit a truncation warning)."""
-    import jax
     return jnp.float64 if jax.config.jax_enable_x64 else dtype
 
 
@@ -155,6 +163,84 @@ def _icfft_crop(z, keep, engine):
         from repro.kernels import ops
         return ops.ifft_pruned(z, keep, max_radix=engine.max_radix)
     return _cfft(z, engine, inverse=True)[..., :keep]
+
+
+# ---------------------------------------------------------------------------
+# DFT-matrix products (the MXU route): no pad before the pruned forward, no
+# crop after the pruned inverse -- the matrices hold only the live rows and
+# columns
+# ---------------------------------------------------------------------------
+
+# DFT lengths the MXU route takes on the TPU, set from a chip
+# micro-benchmark of jnp.fft against the products (benchmarks/bench_mxu_dft.py)
+MXU_DFT_LENGTHS = (256, 512, 1024)
+
+
+def _roots(n: int):
+    """``e^{-2 pi i r / n}`` for ``r = 0..n-1`` in float64, exact at the
+    quarter turns (so the imaginary parts a c2r inverse drops are 0)."""
+    r = np.arange(n)
+    w = np.exp(-2j * np.pi * r / n)
+    quarter = (4 * r) % n == 0
+    w[quarter] = np.array([1, -1j, -1, 1j])[4 * r[quarter] // n]
+    return w
+
+
+@lru_cache(maxsize=None)
+def dft_matrices(dft: str, n_fft: int, n_in: int) -> dict:
+    """Plan-time matrices of a length-``n_fft`` DFT direction whose forward
+    reads ``n_in`` live inputs and whose inverse keeps the first ``n_in``
+    outputs; built in float64 with the exact reduction ``(j k) mod n_fft``,
+    then cast to complex64.
+
+      fwd  ``F[:n_in, :n_out]``: ``n_out`` = ``n_fft // 2 + 1`` bins for
+           ``dft="r2c"``, ``n_fft`` for ``"c2c"``
+      bwd  c2c: ``conj(F)[:n_fft, :n_in] / n_fft``; r2c: ``B[k, j] =
+           w_k e^{+2 pi i j k / n_fft} / n_fft`` over the half spectrum,
+           with the Hermitian weights ``w_k`` (1 at DC and Nyquist, 2
+           elsewhere) folded in, so that the c2r inverse of ``y`` is
+           ``Re(y) @ Re(B) - Im(y) @ Im(B)``
+    """
+    w = _roots(n_fft)
+    n_out = n_fft // 2 + 1 if dft == "r2c" else n_fft
+    fwd = w[np.outer(np.arange(n_in), np.arange(n_out)) % n_fft]
+    bwd = np.conj(w[np.outer(np.arange(n_out), np.arange(n_in)) % n_fft])
+    if dft == "r2c":
+        weight = np.full(n_out, 2.0)
+        weight[0] = 1.0
+        if n_fft % 2 == 0:
+            weight[-1] = 1.0
+        bwd = bwd * weight[:, None]
+    return {"dft": dft, "fwd": fwd.astype(np.complex64),
+            "bwd": (bwd / n_fft).astype(np.complex64)}
+
+
+def _dot(x, m):
+    """``x @ m`` over the last axis of ``x`` at float32 precision (on the
+    TPU, ``HIGHEST`` is 6 bf16 passes of the MXU)."""
+    m = jnp.asarray(m, dtype=x.dtype)
+    return jax.lax.dot_general(x, m, (((x.ndim - 1,), (0,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST)
+
+
+def mxu_dft_forward(x, mats):
+    """The spectrum of the live inputs ``x`` (``[..., n_in]``, real or
+    complex) as ``x @ F``: the r2c half spectrum or the c2c full one, equal
+    to ``jnp.fft.rfft``/``fft`` of ``x`` zero-extended to ``n_fft``."""
+    f = mats["fwd"]
+    if jnp.iscomplexobj(x):
+        return _dot(x, f)
+    return jax.lax.complex(_dot(x, f.real), _dot(x, f.imag))
+
+
+def mxu_dft_backward(y, mats):
+    """The first ``n_in`` samples of the inverse of the spectrum ``y``:
+    ``jnp.fft.ifft`` (c2c) or ``jnp.fft.irfft`` (r2c, a real result; the
+    imaginary parts of the DC and Nyquist bins are ignored, as there)."""
+    b = mats["bwd"]
+    if mats["dft"] == "c2c":
+        return _dot(y, b)
+    return _dot(y.real, b.real) - _dot(y.imag, b.imag)
 
 
 def _post(re, im, a, b, engine, out_dtype):

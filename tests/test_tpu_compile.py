@@ -131,3 +131,24 @@ def test_kernel_lengths_run_the_kernel(compile_tpu):
         lambda x: tr._icfft_crop(tr._cfft(x, eng), 512, eng).real,
         (ROWS, 1024))
     assert hlo.count("tpu_custom_call") == 3
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("dft", ["r2c", "c2c"])
+def test_mxu_dft_products_compile_for_v5e(compile_tpu, dft, direction):
+    """The MXU route's products at the one-chip 256^3 solve's widths (a
+    512-point DFT with 257 live points) compile for a v5e as convolutions
+    at the highest precision, with no FFT left."""
+    mats = tr.dft_matrices(dft, 512, 257)
+    n_out = 257 if dft == "r2c" else 512
+    if direction == "fwd" and dft == "r2c":
+        hlo = compile_tpu(lambda x: tr.mxu_dft_forward(x, mats).imag,
+                          (ROWS, 257))
+    elif direction == "fwd":
+        hlo = compile_tpu(lambda r, i: tr.mxu_dft_forward(
+            jax.lax.complex(r, i), mats).imag, (ROWS, 257), (ROWS, 257))
+    else:
+        hlo = compile_tpu(lambda r, i: jnp.real(tr.mxu_dft_backward(
+            jax.lax.complex(r, i), mats)), (ROWS, n_out), (ROWS, n_out))
+    assert "convolution" in hlo and " fft(" not in hlo
+    assert "operand_precision={highest,highest}" in hlo
