@@ -147,7 +147,8 @@ class DistributedPoissonSolver:
         # ABFT checksum tolerance; 0.0 = auto per data dtype (abft.tol_for)
         self.abft_rtol = float(abft_rtol)
         self.stats = {"solves": 0, "retries": 0, "verify_failures": 0,
-                      "degradations": []}
+                      "degradations": [],
+                      "placements": {"device": 0, "put": 0}}
         # the mesh as the caller passed it keys the get_solver cache (and
         # its eviction on rebuild); the solver places its arrays on the
         # Auto-axis view of it
@@ -210,6 +211,7 @@ class DistributedPoissonSolver:
         # what each switch that emits a collective ships per chip and field
         self.stats["switch_bytes"] = switch_bytes(self.plan, p1, p2, dtype)
         self._crops = {}
+        self._places = {}
 
         gdtype = np.float64 if dtype == jnp.float64 else np.float32
         gshape = tuple(
@@ -715,10 +717,16 @@ class DistributedPoissonSolver:
         under ``lite`` the SAME jit as verify-off runs (the sandwich is
         entirely host-side) and ``(u, qs, w_valid, w_norm)`` returns (or
         None when the sandwich is unavailable for this config)."""
-        with spans.span("flups.pad"):
-            fp = self._pad_input(f)
-            spec = self.input_spec(local_batch)
-            fp = jax.device_put(fp, NamedSharding(self.mesh, spec))
+        with spans.span("flups.pad") as sp:
+            sharding = NamedSharding(self.mesh, self.input_spec(local_batch))
+            route = self._route(f, sharding)
+            if route == "device":
+                fp = self._place_for(f.shape, f.sharding, local_batch)(f)
+            else:
+                fp = jax.device_put(self._pad_input(f), sharding)
+            self.stats["placements"][route] += 1
+            if sp.on:
+                sp.set("route", route)
         names = rep = None
         if lite:
             ent = self._lite_pair(fp.shape, local_batch)
@@ -746,6 +754,42 @@ class DistributedPoissonSolver:
         if lite:
             return (out,) + ent
         return (out, names, rep) if abft else out
+
+    def _route(self, f, sharding):
+        """How ``_dispatch`` places ``f`` on the pencil ``sharding``:
+        ``"device"`` when ``f`` is an array committed to exactly the mesh's
+        devices whose blocks differ from the pencil's (each chip pads its
+        own block from what it holds, ``_place_for``); ``"put"`` otherwise:
+        an input that already has the pencil's blocks, one on other devices
+        and one from the host go through ``jax.device_put``.  A
+        ``device_put`` between two layouts of the mesh's devices with no
+        source buffer per pencil block copies the whole field through the
+        host."""
+        if not (isinstance(f, jax.Array) and f.committed
+                and f.sharding.device_set == sharding.device_set):
+            return "put"
+        shape = f.shape[:-3] + self.padded_input_shape()
+        if shape == f.shape and f.sharding.is_equivalent_to(sharding,
+                                                            f.ndim):
+            return "put"
+        return "device"
+
+    def _place_for(self, shape, sharding, local_batch: bool):
+        """The jitted pad of an input of ``shape`` held as ``sharding`` onto
+        the pencil sharding (cached), under the scope ``place``.  The input
+        is the caller's and is not donated; the output is a fresh buffer,
+        which the solve donates."""
+        key = (tuple(shape), sharding, bool(local_batch))
+        fn = self._places.get(key)
+        if fn is None:
+            def place(x):
+                with jax.named_scope("place"):
+                    return self._pad_input(x)
+
+            fn = jax.jit(place, out_shardings=NamedSharding(
+                self.mesh, self.input_spec(local_batch)))
+            self._places[key] = fn
+        return fn
 
     def _crop_output(self, out):
         """The solve's output cut to the user grid: the mesh-multiple

@@ -109,7 +109,7 @@ def test_spans_of_a_miss_and_a_hit(spans_off):
         ("flups.build", "flups.get_solver", 0, {}),
         ("flups.get_solver", None, 0, {"hit": False}),
         ("flups.get_solver", None, 0, {"hit": True}),
-        ("flups.pad", "flups.solve", 0, {}),
+        ("flups.pad", "flups.solve", 0, {"route": "put"}),
         ("flups.green.put", "flups.solve", 0, {}),
         ("flups.launch", "flups.solve", 0, {}),
         ("flups.crop", "flups.solve", 0, {}),
@@ -120,7 +120,7 @@ def test_spans_of_a_miss_and_a_hit(spans_off):
         hit = spans.drain()
         assert _tree(hit) == [
             ("flups.get_solver", None, k, {"hit": True}),
-            ("flups.pad", "flups.solve", k, {}),
+            ("flups.pad", "flups.solve", k, {"route": "put"}),
             ("flups.launch", "flups.solve", k, {}),
             ("flups.crop", "flups.solve", k, {}),
             ("flups.solve", None, k, {}),
