@@ -1,11 +1,12 @@
 """Batched multi-RHS solve sweep: B in {1, 2, 4, 8, 16}, batched pipeline
-vs B sequential solves, for both solvers on the paper's fully-unbounded
-workload -- the amortization a vortex-method driver (several RHS per
-timestep over one plan) gets for free from the batch axis.
+vs B sequential solves, for the solver on one device and on a mesh, on
+the paper's fully-unbounded workload -- the amortization a vortex-method
+driver (several RHS per timestep over one plan) gets for free from the
+batch axis.
 
-``PoissonSolver`` runs in-process; ``DistributedPoissonSolver`` runs on an
-8-device host-platform (2 x 4) pencil mesh in a subprocess (same pattern
-as bench_comm).  The headline number -- the acceptance bar of the batched
+``DistributedPoissonSolver`` runs without a mesh (one device, row
+"poisson") and on an 8-device host-platform (2 x 4) pencil mesh (row
+"pencil"), in a subprocess (same pattern as bench_comm).  The headline number -- the acceptance bar of the batched
 execution PR -- is the distributed B=8 speedup: one batched solve vs 8
 sequential solves on the host mesh.  Plus one Biot-Savart row: the
 uniform-plan batched 3-component pipeline vs the sequential per-component
@@ -30,7 +31,6 @@ import numpy as np
 import jax, jax.numpy as jnp
 from repro.core.bc import BCType, DataLayout
 from repro.core.comm import CommConfig
-from repro.core.solver import PoissonSolver
 from repro.distributed.pencil import DistributedPoissonSolver
 from repro.core.biot_savart import BiotSavartSolver
 
@@ -63,8 +63,8 @@ def sweep(name, solver):
                      "loop_ms": t_loop * 1e3, "batch_ms": t_batch * 1e3,
                      "speedup": t_loop / t_batch})
 
-sweep("poisson", PoissonSolver((n, n, n), 1.0, (U, U, U),
-                               layout=DataLayout.CELL))
+sweep("poisson", DistributedPoissonSolver((n, n, n), 1.0, (U, U, U),
+                                          layout=DataLayout.CELL))
 sweep("pencil", DistributedPoissonSolver(
     (n, n, n), 1.0, (U, U, U), mesh=mesh,
     comm=CommConfig("overlap", 2)))

@@ -9,13 +9,13 @@ jax.config.update("jax_enable_x64", True)
 
 from repro.core.bc import BCType, DataLayout
 from repro.core.green import GreenKind
-from repro.core.solver import PoissonSolver
+from repro.core.solver import get_solver
 
 N, L = 64, 1.0
 U = (BCType.UNB, BCType.UNB)
 
-solver = PoissonSolver((N, N, N), L, (U, U, U), layout=DataLayout.NODE,
-                       green_kind=GreenKind.HEJ4)
+solver = get_solver((N, N, N), L, (U, U, U), layout=DataLayout.NODE,
+                    green_kind=GreenKind.HEJ4)
 
 # a Gaussian bump as the right-hand side: the potential is analytic
 from scipy.special import erf

@@ -1,4 +1,4 @@
-"""FFT-based Poisson solver (the flups pipeline), single-process reference.
+"""The flups plan, its Green's function, and the solver cache.
 
 The solve is the paper's algorithm:
 
@@ -11,32 +11,32 @@ The solve is the paper's algorithm:
   backward: inverse transforms in reverse order, crop, write back the
             convention-overwritten boundary values.
 
-The distributed version (``repro.core.comm`` + ``repro.distributed``) swaps
-the axis shuffles for pencil topology switches; the per-direction math here
-is reused unchanged.
+Here live the plan (``make_plan``: per-direction transforms, paddings and
+execution order) and the Green's function aligned with the spectral
+storage (``build_green``).  The solver is
+``repro.distributed.pencil.DistributedPoissonSolver``, on a device mesh or,
+without one, on a 1x1 mesh of the first device; it swaps the axis
+shuffles for pencil topology switches and runs the per-direction math of
+``repro.core.engine``.  ``get_solver`` is the entry point: it builds that
+solver once per plan identity and caches it.
 """
 from __future__ import annotations
 
 import threading
 from collections import OrderedDict
-from dataclasses import dataclass, field
-from functools import partial
+from dataclasses import dataclass
 
 import numpy as np
-import jax
-import jax.numpy as jnp
 import scipy.fft as sfft
 
-from .bc import (BCType, DataLayout, DirBC, TransformKind, r2r_kind,
-                 INVERSE_KIND)
+from .bc import BCType, DataLayout, DirBC, TransformKind, r2r_kind
 from . import transforms as tr
 from . import green as gr
 from repro.runtime import spans
-from .engine import (RELAYOUT_MODES, as_engine, build_schedule,
-                     folded_normfact, fwd_1d, bwd_1d, relayout as _relayout,
-                     schedule_layouts)
+from .engine import as_engine, folded_normfact, fwd_1d, bwd_1d, \
+    schedule_layouts
 
-__all__ = ["Plan1D", "PoissonPlan", "PoissonSolver", "make_plan",
+__all__ = ["Plan1D", "PoissonPlan", "make_plan",
            "get_solver", "clear_solver_cache", "solver_cache_info",
            "set_solver_cache_capacity", "evict_solver_entries",
            "evict_solver_instance"]
@@ -400,338 +400,6 @@ _bwd_1d = bwd_1d
 
 
 # ---------------------------------------------------------------------------
-# solver
-# ---------------------------------------------------------------------------
-
-def _fresh_jit(impl):
-    """``jax.jit`` over a FRESH function object.  jitting a bound method
-    directly shares jax's global trace cache across wrappers of the same
-    method, so a post-reconfigure ``jax.jit(self._solve_impl)`` can silently
-    replay a stale (or fault-tainted) trace whenever the call signature
-    coincides; a unique closure per wrapper guarantees the retrace."""
-    def call(f):
-        return impl(f)
-    return jax.jit(call)
-
-
-class PoissonSolver:
-    """u = solve(f): FFT-based solution of lap(u) = f with mixed BCs.
-
-    ``engine``: "xla" (default) or "pallas" -- see ``repro.core.engine``.
-
-    ``solve`` accepts ``f`` of shape ``(*grid)`` (one rhs) or ``(B, *grid)``
-    (B independent right-hand sides sharing this plan, solved in ONE fused
-    pipeline -- same transform count, bigger row batches).  One jit
-    specialization exists per input rank/shape; the plan, schedule and
-    Green's function are shared by all of them.
-
-    Resilience (DESIGN.md #10): every ``solve`` runs under the graceful-
-    degradation ladder -- on failure the solver retries transient errors
-    with bounded backoff, then steps its config down one rung at a time
-    (``pallas -> xla``, ``scheduled -> baseline``, ``deferred -> upfront``),
-    rebuilding the pipeline each rung; the trail lands in
-    ``self.stats["degradations"]`` and a terminal failure raises
-    ``repro.runtime.SolveError`` with stage provenance.  ``verify``
-    ("nan" | "residual", default off) arms the numerical health guards on
-    every solve; a tripped guard walks the same ladder.
-    """
-
-    def __init__(self, shape, L, bcs, layout=DataLayout.CELL,
-                 green_kind=gr.GreenKind.CHAT2, eps_factor=2.0,
-                 engine="xla", doubling="deferred", relayout="scheduled",
-                 order_policy="layout", verify=None, verify_rtol=0.5,
-                 abft_rtol=0.0):
-        assert relayout in RELAYOUT_MODES, relayout
-        assert verify in (None, "nan", "residual", "abft",
-                          "abft-stages"), verify
-        self._base = dict(shape=tuple(shape), L=L, bcs=bcs, layout=layout,
-                          green_kind=green_kind, eps_factor=eps_factor,
-                          order_policy=order_policy)
-        self.verify = verify
-        self.verify_rtol = float(verify_rtol)
-        # ABFT checksum tolerance; 0.0 = auto per data dtype (abft.tol_for)
-        self.abft_rtol = float(abft_rtol)
-        self.stats = {"solves": 0, "retries": 0, "verify_failures": 0,
-                      "degradations": []}
-        self._engine_obj = as_engine(engine)
-        self._configure({"engine": self._engine_obj.name,
-                         "doubling": doubling, "relayout": relayout})
-
-    def _configure(self, cfg: dict):
-        """(Re)build the whole pipeline for one runtime config -- the
-        degradation ladder's rebuild hook (also the constructor's builder).
-        A fresh ``jax.jit`` wrapper is installed every time, so a retry
-        after a trace-time fault re-traces instead of replaying a poisoned
-        cache entry."""
-        b = self._base
-        self._cfg = dict(cfg)
-        with spans.span("flups.plan"):
-            self.plan = make_plan(b["shape"], b["L"], b["bcs"], b["layout"],
-                                  b["green_kind"], b["eps_factor"],
-                                  doubling=cfg["doubling"],
-                                  order_policy=b["order_policy"])
-            # keep the constructor's engine object (its max_radix and
-            # platform) as long as the ladder has not degraded the name
-            self.engine = (self._engine_obj
-                           if self._engine_obj.name == cfg["engine"]
-                           else as_engine(cfg["engine"]))
-            self.schedule = build_schedule(self.plan, self.engine)
-        self.relayout = cfg["relayout"]
-        # ONE Green copy, held in the layout the selected pipeline
-        # multiplies in: natural for baseline, the spectral LAYOUT (active
-        # axis of the last forward stage minor-most) for scheduled --
-        # permuted once, at plan time
-        with spans.span("flups.green.build"):
-            g = build_green(self.plan)
-        self._green_nat = g          # natural layout: health diagnosis
-        if self.relayout == "scheduled":
-            with spans.span("flups.green.layout"):
-                g = np.ascontiguousarray(
-                    np.transpose(g, self.schedule.layouts.spectral))
-        self._green = g
-        # jit wrappers are keyed by the active fault-plan token: arming a
-        # FaultPlan forces a retrace (the taint/fail_point hooks run at
-        # trace time), and the clean entry is never polluted by a tainted
-        # trace.  ``self._solve`` stays the clean-path jit (public-ish: the
-        # batch benchmark calls it directly).
-        self._solve = _fresh_jit(self._solve_impl)
-        self._solve_jits = {None: self._solve}
-        # ABFT wrappers live in their own caches: they trace DIFFERENT
-        # programs (checksum sandwiches + report outputs), so the clean jit
-        # above stays bit-exact with the checks compiled out.  ``_abft_jits``
-        # holds the fully-checked pipeline (verify="abft-stages" and the
-        # localization re-run); ``_lite_jits`` the cheap end-to-end
-        # linearity sandwich (verify="abft"); ``_lite_weights`` the
-        # plan-time Freivalds pairs (r, w = S^T r), rebuilt per config
-        self._abft_jits = {}
-        self._lite_jits = {}
-        self._lite_weights = {}
-
-    def _jitted(self):
-        from repro.runtime import faults
-        tok = faults.plan_token()
-        fn = self._solve_jits.get(tok)
-        if fn is None:
-            fn = _fresh_jit(self._solve_impl)
-            self._solve_jits[tok] = fn
-        return fn
-
-    def _abft_tol(self, dtype) -> float:
-        from repro.runtime import abft
-        return self.abft_rtol or abft.tol_for(dtype)
-
-    def _abft_fresh_jit(self):
-        """Jit wrapper of the CHECKED pipeline: returns ``(u, report)``
-        where the report vector stacks every stage's mismatch scalar; the
-        stage names are captured into ``holder`` at trace time."""
-        from repro.runtime import abft
-        impl = self._solve_impl
-        holder: list = []
-
-        def call(f):
-            col = abft.Collector()
-            u = impl(f, col=col, tol=self._abft_tol(f.dtype))
-            holder[:] = col.names
-            return u, col.stacked()
-
-        return jax.jit(call), holder
-
-    def _abft_jitted(self):
-        from repro.runtime import faults
-        tok = faults.plan_token()
-        ent = self._abft_jits.get(tok)
-        if ent is None:
-            ent = self._abft_jits[tok] = self._abft_fresh_jit()
-        return ent
-
-    def _lite_reference_impl(self):
-        """XLA baseline pipeline used only to build the sandwich weight
-        ``w = S^T r`` via vjp.  Autodiff-safe regardless of the active
-        engine (Pallas kernels carry no vjp rules) and within sandwich
-        tolerance of every engine/relayout rung: same linear operator up
-        to roundoff."""
-        from .engine import (build_schedule, crop_doubling,
-                             materialize_doubling)
-        plan = self.plan
-        sched = build_schedule(plan, as_engine("xla"))
-        green = self._green_nat
-
-        def impl(f):
-            g = jnp.asarray(green).astype(f.dtype)
-            y = materialize_doubling(f, plan.dirs)
-            for d in plan.order:
-                y = sched.fwd_chunk(y, d)
-            y = sched.green_multiply(y, g)
-            for d in reversed(plan.order):
-                y = sched.bwd_chunk(y, d)
-            if jnp.iscomplexobj(y):
-                y = y.real
-            return crop_doubling(y, plan.dirs).astype(f.dtype)
-
-        return impl
-
-    def _lite_pair(self, shape, dtype):
-        """Plan-time Freivalds pair for one input signature: the fixed
-        probe ``r`` and the weight ``w = S^T r`` (one vjp of the linear
-        solve, traced under fault suppression so an armed plan cannot
-        poison the reference side)."""
-        from repro.runtime import abft, faults
-        key = (tuple(shape), jnp.dtype(dtype).name)
-        rw = self._lite_weights.get(key)
-        if rw is None:
-            r = jnp.asarray(abft.lite_probe(shape, dtype))
-            ref = self._lite_reference_impl()
-            with faults.suppressed():
-                w = jax.jit(lambda rr: jax.vjp(
-                    ref, jnp.zeros(shape, dtype))[1](rr)[0])(r)
-                jax.block_until_ready(w)
-            rw = self._lite_weights[key] = (r, w)
-        return rw
-
-    def _lite_jitted(self, shape, dtype):
-        """Jit of the clean pipeline plus the end-to-end linearity
-        sandwich: returns ``(u, [<r,u>, <w,f>, ||u||^2])`` -- two fused
-        multiply-reduces on top of the solve, nothing per-stage."""
-        from repro.runtime import faults
-        tok = faults.plan_token()
-        key = (tuple(shape), jnp.dtype(dtype).name, tok)
-        fn = self._lite_jits.get(key)
-        if fn is None:
-            r, w = self._lite_pair(shape, dtype)
-            impl = self._solve_impl
-
-            def call(f):
-                u = impl(f)
-                rep = jnp.stack([jnp.sum(r * u), jnp.sum(w * f),
-                                 jnp.sum(u * u)])
-                return u, rep
-
-            fn = self._lite_jits[key] = jax.jit(call)
-        return fn
-
-    @property
-    def input_shape(self):
-        return self.plan.input_shape
-
-    def _solve_impl(self, f, col=None, tol=None):
-        if self.relayout == "scheduled":
-            return self._solve_scheduled(f, col, tol)
-        from .engine import crop_doubling, materialize_doubling
-        plan = self.plan
-        sched = self.schedule
-        green = jnp.asarray(self._green).astype(f.dtype)
-        y = materialize_doubling(f, plan.dirs)   # no-op when deferred
-        for d in plan.order:
-            y = sched.fwd_chunk(y, d, col, tol)
-        y = sched.green_multiply(y, green, col, tol)
-        for d in reversed(plan.order):
-            y = sched.bwd_chunk(y, d, col, tol)
-        if jnp.iscomplexobj(y):
-            y = y.real
-        y = crop_doubling(y, plan.dirs)
-        return y.astype(f.dtype)
-
-    def _solve_scheduled(self, f, col=None, tol=None):
-        """Layout-scheduled pipeline (DESIGN.md #9): one composed transpose
-        per direction change (where the baseline moveaxis round trips paid
-        two), transforms always on the minor-most axis, Green multiplied in
-        the spectral layout, and -- on the Pallas engine -- the last
-        forward FFT running the Green multiply as an in-register epilogue.
-        Bit-exact vs the baseline path on the XLA engine (transposes only
-        reorder rows; the per-row math is identical)."""
-        from .engine import crop_doubling, materialize_doubling
-        plan = self.plan
-        sched = self.schedule
-        lay = sched.layouts
-        nat = tuple(range(len(plan.dirs)))
-        green = jnp.asarray(self._green).astype(f.dtype)
-        y = materialize_doubling(f, plan.dirs)   # no-op when deferred
-        cur = nat
-        for i, d in enumerate(plan.order[:-1]):
-            y = _relayout(y, cur, lay.fwd[i])
-            cur = lay.fwd[i]
-            y = sched.fwd_last(y, d, col, tol)
-        d_last = plan.order[-1]
-        y = _relayout(y, cur, lay.spectral)
-        y = sched.fwd_last_green(y, d_last, green, col, tol)
-        cur = lay.spectral
-        for i, d in enumerate(reversed(plan.order)):
-            y = _relayout(y, cur, lay.bwd[i])
-            cur = lay.bwd[i]
-            y = sched.bwd_last(y, d, col, tol)
-        y = _relayout(y, cur, nat)
-        if jnp.iscomplexobj(y):
-            y = y.real
-        y = crop_doubling(y, plan.dirs)
-        return y.astype(f.dtype)
-
-    def solve(self, f, verify=None):
-        """Solve for ``f``; ``verify`` overrides the constructor-level
-        health-guard mode for this call ("nan" | "residual" | "abft" |
-        "abft-stages" | None).  ``"abft"`` (DESIGN.md #13) is the
-        two-phase guard: every solve runs the cheap end-to-end linearity
-        sandwich, and only a tripped sandwich re-dispatches through the
-        fully-checked pipeline to localize the stage, selectively repair
-        it, and raise ``IntegrityError`` into the degradation ladder if
-        the corruption persists.  ``"abft-stages"`` runs the checked
-        pipeline unconditionally (per-stage sandwiches with inline
-        selective recompute -- the chaos net's mode)."""
-        with spans.span("flups.solve", closes_step=True):
-            return self._run_solve(f, verify)
-
-    def _run_solve(self, f, verify):
-        from repro.runtime import abft, faults, health, resilience
-        f = jnp.asarray(f)
-        grid = self.input_shape
-        assert (f.ndim in (len(grid), len(grid) + 1)
-                and f.shape[f.ndim - len(grid):] == grid), (f.shape, grid)
-        verify = self.verify if verify is None else verify
-        self.stats["solves"] += 1
-
-        def checked():
-            fn, names = self._abft_jitted()
-            u, rep = fn(f)
-            abft.verify_report(
-                list(names), np.asarray(rep),
-                tol=self._abft_tol(f.dtype), stats=self.stats,
-                describe="solve")
-            return u
-
-        def attempt():
-            faults.fail_point("solve.dispatch")
-            if verify == "abft-stages":
-                return checked()
-            if verify == "abft":
-                u, rep = self._lite_jitted(f.shape, f.dtype)(f)
-                m = abft.lite_mismatch(np.asarray(rep))
-                tol = self._abft_tol(f.dtype) * abft.LITE_HEADROOM
-                if m <= tol:
-                    return u
-                # sandwich tripped: localize via the checked pipeline
-                # (selective inline repair; persistent corruption raises
-                # IntegrityError out of verify_report into the ladder)
-                self.stats["verify_failures"] += 1
-                self.stats.setdefault("integrity", []).append({
-                    "stage": "solve.linearity", "kind": "linearity",
-                    "mismatch": float(m), "tol": float(tol),
-                    "action": "localize", "describe": "solve"})
-                return checked()
-            with spans.span("flups.launch"):
-                u = self._jitted()(f)
-            if verify:
-                health.check_solution(
-                    u, f, self.plan, mode=verify, rtol=self.verify_rtol,
-                    stats=self.stats,
-                    locate=lambda: health.locate_nonfinite_stage(
-                        self.plan, self.schedule, f, self._green_nat))
-            return u
-
-        return resilience.run_with_ladder(
-            attempt, config=self._cfg, reconfigure=self._configure,
-            stats=self.stats, describe="solve")
-
-
-# ---------------------------------------------------------------------------
 # global plan/solver cache
 # ---------------------------------------------------------------------------
 #
@@ -783,34 +451,19 @@ def _freeze(v):
         return repr(v)
 
 
-def _build_solver(shape, L, bcs, layout, green_kind, eps_factor, engine,
-                  doubling, relayout, order_policy, mesh, kw):
-    if mesh is not None:
-        from repro.distributed.pencil import DistributedPoissonSolver
-        return DistributedPoissonSolver(shape, L, bcs, layout, green_kind,
-                                        mesh=mesh, eps_factor=eps_factor,
-                                        engine=engine, doubling=doubling,
-                                        relayout=relayout,
-                                        order_policy=order_policy, **kw)
-    assert set(kw) <= {"verify", "verify_rtol", "abft_rtol"}, \
-        f"unexpected single-process solver kwargs: {kw}"
-    return PoissonSolver(shape, L, bcs, layout, green_kind, eps_factor,
-                         engine=engine, doubling=doubling,
-                         relayout=relayout, order_policy=order_policy, **kw)
-
-
 def get_solver(shape, L, bcs, layout=DataLayout.CELL,
                green_kind=gr.GreenKind.CHAT2, eps_factor=2.0,
                engine="xla", doubling="deferred", relayout="scheduled",
                order_policy="layout", *, mesh=None, **kw):
-    """Construct-or-fetch a solver from the global plan cache.
+    """Construct-or-fetch a ``DistributedPoissonSolver`` from the global
+    plan cache.
 
-    Returns a ``PoissonSolver``, or a ``DistributedPoissonSolver`` when
-    ``mesh`` is given (extra distributed keywords -- ``comm``, ``axes``,
-    ``batch_axis``, ``dtype``, autotune knobs, ... -- pass through and are
-    part of the cache key, as is the mesh itself: same devices + same axis
-    names hit the same entry).  Entries are evicted least-recently-used
-    beyond ``set_solver_cache_capacity`` (default 16 solvers).
+    ``mesh`` (default None: a 1x1 mesh on the first device) and the extra
+    keywords -- ``comm``, ``axes``, ``batch_axis``, ``dtype``, autotune
+    knobs, ... -- pass through and are part of the cache key: same devices
+    + same axis names hit the same entry, and every call without a mesh
+    hits the same entry.  Entries are evicted least-recently-used beyond
+    ``set_solver_cache_capacity`` (default 16 solvers).
 
     Construction is SINGLE-FLIGHT per key: when N threads miss the same
     key concurrently (the serve thundering herd), exactly one of them
@@ -821,8 +474,7 @@ def get_solver(shape, L, bcs, layout=DataLayout.CELL,
     """
     with spans.span("flups.get_solver") as sp:
         from repro.runtime import faults
-        key = ("dist" if mesh is not None else "single",
-               _freeze(shape), _freeze(L), _freeze(bcs), _freeze(layout),
+        key = (_freeze(shape), _freeze(L), _freeze(bcs), _freeze(layout),
                _freeze(green_kind), float(eps_factor),
                as_engine(engine), str(doubling), str(relayout),
                str(order_policy), _freeze(mesh), _freeze(kw),
@@ -855,10 +507,12 @@ def get_solver(shape, L, bcs, layout=DataLayout.CELL,
             return build.result
         sp.set("hit", False)
         try:
+            from repro.distributed.pencil import DistributedPoissonSolver
             with spans.span("flups.build"):
-                s = _build_solver(shape, L, bcs, layout, green_kind,
-                                  eps_factor, engine, doubling, relayout,
-                                  order_policy, mesh, kw)
+                s = DistributedPoissonSolver(
+                    shape, L, bcs, layout, green_kind, mesh=mesh,
+                    eps_factor=eps_factor, engine=engine, doubling=doubling,
+                    relayout=relayout, order_policy=order_policy, **kw)
         except BaseException as e:
             with _SOLVER_CACHE_LOCK:
                 _SOLVER_BUILDS.pop(key, None)

@@ -1,4 +1,6 @@
-"""Distributed pencil-decomposition Poisson solver (shard_map + collectives).
+"""The Poisson solver: pencil decomposition over a device mesh (shard_map +
+collectives).  It is the one solver class; built without a mesh it runs on
+a 1x1 mesh of the first device, where every topology switch is local.
 
 The 2-D process grid (P1, P2) lives on two named mesh axes; every topology
 switch is scoped to exactly ONE axis (the paper's sub-communicators).  The
@@ -34,8 +36,8 @@ Uneven data counts (the node-centered N+1 problem the paper's Appendix A
 load balancing solves for MPI) are handled on TPU by padding the *inactive*
 (sharded) axes to a multiple of the mesh axis size: XLA's all-to-all
 requires equal splits.  The active axis is always local and exact, so the
-transforms, paddings and boundary conventions are identical to the
-reference solver.  ``repro.core.partition`` remains the source of truth for
+transforms, paddings and boundary conventions are identical to a solve on
+one device.  ``repro.core.partition`` remains the source of truth for
 how a real uneven MPI partition would be laid out (and is what the
 CPU-cluster deployment path would use).
 """
@@ -48,7 +50,7 @@ from functools import partial
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.sharding import AxisType, PartitionSpec as P, NamedSharding
+from jax.sharding import AxisType, Mesh, PartitionSpec as P, NamedSharding
 
 from repro.core.bc import DataLayout
 from repro.core import green as gr
@@ -77,14 +79,33 @@ def _auto_axes(mesh):
     return mesh.update(axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
+def _green_dtype(dtype):
+    """Host and device dtype of the Green's function for solves in
+    ``dtype``; a solver without a dtype keeps it in float64 on the host and
+    casts it per input dtype."""
+    return np.float64 if dtype is None or dtype == jnp.float64 \
+        else np.float32
+
+
+# ``dtype``'s default: float32 on a mesh the caller gives, the input's own
+# dtype (None) on the one-device mesh built without one
+_DTYPE_DEFAULT = object()
+
 # axis pad/crop shared with the comm chunking layer
 _pad_dim = pad_axis
 _crop_dim = crop_axis
 
 
 class DistributedPoissonSolver:
-    """Pencil-distributed flups solve over a (P1, P2) mesh-axis pair.
+    """u = solve(f): the flups solve of lap(u) = f with mixed BCs, pencil-
+    distributed over a (P1, P2) mesh-axis pair.
 
+    ``mesh``: the device mesh; None builds a 1x1 mesh of ``axes`` on the
+    first device.
+    ``dtype``: what the solve casts its input to and returns (default
+    float32).  Without a mesh the default is None, which keeps the input's
+    dtype (one program and one device Green per dtype); a solver on a given
+    mesh has a dtype.
     ``axes``: the two mesh axis names forming the process grid.
     ``batch_axis``: optional extra mesh axis (e.g. "pod"): the solver then
     takes a leading batch dimension sharded over that axis (data-parallel
@@ -111,9 +132,9 @@ class DistributedPoissonSolver:
     """
 
     def __init__(self, shape, L, bcs, layout=DataLayout.CELL,
-                 green_kind=gr.GreenKind.CHAT2, *, mesh, axes=("data", "model"),
-                 comm=CommConfig(), batch_axis=None,
-                 eps_factor: float = 2.0, dtype=jnp.float32,
+                 green_kind=gr.GreenKind.CHAT2, *, mesh=None,
+                 axes=("data", "model"), comm=CommConfig(), batch_axis=None,
+                 eps_factor: float = 2.0, dtype=_DTYPE_DEFAULT,
                  lazy_green: bool = False, engine="xla",
                  doubling: str = "deferred", relayout: str = "scheduled",
                  order_policy: str = "layout",
@@ -126,9 +147,27 @@ class DistributedPoissonSolver:
         assert verify in (None, "nan", "residual", "abft",
                           "abft-stages"), verify
         assert autotune_search in ("guided", "brute"), autotune_search
-        # the engine routes FFT lengths by the platform the mesh's devices
-        # are on (they may be described devices of an ahead-of-time compile)
-        self._platform = mesh.devices.flat[0].platform
+        # the mesh as the caller passed it (None: none) keys the get_solver
+        # cache (and its eviction on rebuild) and decides whether a solve
+        # may consume its input; the solver places its arrays on the
+        # Auto-axis view of the mesh it runs on
+        self._caller_mesh = mesh
+        if mesh is None:
+            # the engine keeps its own platform (default: JAX's default
+            # backend, the platform of the device the mesh is built on)
+            self._platform = None
+            mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1),
+                        tuple(axes))
+            dtype = None if dtype is _DTYPE_DEFAULT else dtype
+        else:
+            # the engine routes FFT lengths by the platform the mesh's
+            # devices are on (they may be described devices of an
+            # ahead-of-time compile)
+            self._platform = mesh.devices.flat[0].platform
+            if dtype is _DTYPE_DEFAULT:
+                dtype = jnp.float32
+            # plan-time work (switch bytes, autotune) counts in this dtype
+            assert dtype is not None, "a solver on a given mesh has a dtype"
         # full construction identity, kept for _configure (ladder rebuilds)
         # and rebuild(mesh) (elastic recovery re-plans)
         self._ctor = dict(shape=tuple(shape), L=L, bcs=bcs, layout=layout,
@@ -149,10 +188,6 @@ class DistributedPoissonSolver:
         self.stats = {"solves": 0, "retries": 0, "verify_failures": 0,
                       "degradations": [],
                       "placements": {"device": 0, "put": 0}}
-        # the mesh as the caller passed it keys the get_solver cache (and
-        # its eviction on rebuild); the solver places its arrays on the
-        # Auto-axis view of it
-        self._caller_mesh = mesh
         self.mesh = _auto_axes(mesh)
         self.axes = tuple(axes)
         self.batch_axis = batch_axis
@@ -164,8 +199,20 @@ class DistributedPoissonSolver:
         self._configure({"engine": as_engine(engine).name, "comm": None,
                          "doubling": doubling, "relayout": relayout})
 
+    def _solve_dtype(self, dtype=None):
+        """The dtype a solve runs in: the solver's; without one (built
+        without a mesh), ``dtype`` (an input's); without either, float32,
+        which is what that solver's plan-time work (switch byte counts,
+        dry-run lowering) assumes."""
+        for d in (self.dtype, dtype):
+            if d is not None:
+                return jnp.dtype(d)
+        return jnp.dtype(jnp.float32)
+
     def _engine(self, engine):
-        return dataclasses.replace(as_engine(engine), platform=self._platform)
+        eng = as_engine(engine)
+        return eng if self._platform is None \
+            else dataclasses.replace(eng, platform=self._platform)
 
     def _configure(self, cfg: dict):
         """(Re)build plan, Green layout, comm strategy and jits for one
@@ -177,7 +224,7 @@ class DistributedPoissonSolver:
         shape, L, bcs = c["shape"], c["L"], c["bcs"]
         layout, green_kind = c["layout"], c["green_kind"]
         eps_factor, order_policy = c["eps_factor"], c["order_policy"]
-        lazy_green, dtype = c["lazy_green"], c["dtype"]
+        lazy_green, dtype = c["lazy_green"], self._solve_dtype()
         axes, mesh = self.axes, self.mesh
         self._cfg = dict(cfg)
         with spans.span("flups.plan"):
@@ -213,7 +260,7 @@ class DistributedPoissonSolver:
         self._crops = {}
         self._places = {}
 
-        gdtype = np.float64 if dtype == jnp.float64 else np.float32
+        gdtype = _green_dtype(self.dtype)
         gshape = tuple(
             self._PS0 if d == d0 else (self._PS1 if d == d1 else S[d])
             for d in range(3))
@@ -247,7 +294,7 @@ class DistributedPoissonSolver:
         # it), so its spec is the same with or without batch parallelism
         self.g_spec = P(*(spec_g[d] for d in gperm))
         self.in_spec = self.input_spec(local_batch=False)
-        self._green_dev = None
+        self._green_dev = {}
 
         if cfg["comm"] is None:
             # first build: resolve the user's request (incl. "auto")
@@ -268,7 +315,7 @@ class DistributedPoissonSolver:
                 else 1
             self.comm = CommConfig(cfg["comm"], max(nc, 1), prev.fold,
                                    prev.chunk_axis)
-        self._green_dev = None
+        self._green_dev = {}
         self._jits = {}
         # checked (verify="abft-stages" / localization) traces live apart
         # from the clean jits: they emit checksum sandwiches, sidecar
@@ -289,6 +336,7 @@ class DistributedPoissonSolver:
         d0, d1, d2 = self.plan.order
         a1, a2 = self.axes
         U, S = self._U, self._S
+        dtype = x.dtype
         strat = make_strategy(cfg, axis_sizes=self._axis_sizes,
                               abft=None if col is None else (col, tol))
         # leading batch axes (multi-RHS) shift every grid-dim index; they
@@ -327,7 +375,7 @@ class DistributedPoissonSolver:
                                            col, tol))
         if jnp.iscomplexobj(x):
             x = x.real
-        return x.astype(self.dtype)
+        return x.astype(dtype)
 
     def _local_solve_scheduled(self, x, green, *, cfg: CommConfig,
                                col=None, tol=None):
@@ -348,6 +396,7 @@ class DistributedPoissonSolver:
         d0, d1, d2 = self.plan.order
         a1, a2 = self.axes
         U, S = self._U, self._S
+        dtype = x.dtype
         lay = sched.layouts
         L0, L1, L2 = lay.fwd
         B0, B1, B2 = lay.bwd                 # B0 == L2 (spectral layout)
@@ -403,7 +452,7 @@ class DistributedPoissonSolver:
         x = relayout(x, B2, nat)             # edge adapter back
         if jnp.iscomplexobj(x):
             x = x.real
-        return x.astype(self.dtype)
+        return x.astype(dtype)
 
     # -- jit assembly --------------------------------------------------------
 
@@ -450,32 +499,35 @@ class DistributedPoissonSolver:
             out_specs=in_spec, check_vma=False)
         return jax.jit(fn, donate_argnums=(0,) if donate else ())
 
-    def _abft_tol(self) -> float:
+    def _abft_tol(self, dtype) -> float:
         from repro.runtime import abft
-        return self.abft_rtol or abft.tol_for(self.dtype)
+        return self.abft_rtol or abft.tol_for(dtype)
 
-    def abft_jit_for(self, local_batch: bool = False):
+    def abft_jit_for(self, local_batch: bool = False, dtype=None):
         """The CHECKED distributed solve (``verify="abft"``): returns
         ``(fn, names)`` where ``fn(f, green) -> (u, report)``.  The local
         body runs with an ``abft.Collector`` threaded through every
         transform stage and topology switch (the comm strategy ships the
         checksum sidecars), each shard's mismatch vector is max-combined
         across both pencil axes with ``lax.pmax``, and the stage names are
-        captured into ``names`` at trace time."""
+        captured into ``names`` at trace time.  The checksum tolerance is
+        ``dtype``'s (default: the solver's)."""
         from repro.runtime import faults
-        key = (bool(local_batch), faults.plan_token())
+        dtype = self._solve_dtype(dtype)
+        key = (bool(local_batch), dtype, faults.plan_token())
         ent = self._abft_jits.get(key)
         if ent is None:
             ent = self._abft_jits[key] = self._build_abft_jit(
-                self.comm, local_batch=local_batch)
+                self.comm, dtype, local_batch=local_batch)
         return ent
 
-    def _build_abft_jit(self, cfg: CommConfig, local_batch: bool = False):
+    def _build_abft_jit(self, cfg: CommConfig, dtype,
+                        local_batch: bool = False):
         from repro.runtime import abft
         body = (self._local_solve_scheduled if self.relayout == "scheduled"
                 else self._local_solve)
         a1, a2 = self.axes
-        tol = self._abft_tol()
+        tol = self._abft_tol(dtype)
         holder: list = []
 
         def local(x, green):
@@ -504,7 +556,7 @@ class DistributedPoissonSolver:
             out_specs=(in_spec, rep_spec), check_vma=False)
         return jax.jit(fn, donate_argnums=(0,)), holder
 
-    def _lite_pair(self, fp_shape, local_batch: bool):
+    def _lite_pair(self, fp_shape, dtype, local_batch: bool):
         """Plan-time Freivalds material for one padded input signature:
         rank-1 probe factors ``q0, q1, q2`` over the USER grid and the
         host copy of the weight ``w = S^T C^T r`` -- one vjp of the
@@ -521,14 +573,14 @@ class DistributedPoissonSolver:
         differentiate through) or an engine whose kernels carry no vjp
         rules -- and ``solve`` falls back to the checked pipeline."""
         from repro.runtime import abft, faults
-        key = tuple(fp_shape)
+        key = (tuple(fp_shape), jnp.dtype(dtype))
         if key in self._lite_weights:
             return self._lite_weights[key]
         rw = None
         if not self._ctor["lazy_green"]:
             sh = NamedSharding(self.mesh, self.input_spec(local_batch))
             user_grid = tuple(p.n_pts for p in self.plan.dirs)
-            qs = abft.lite_probe_axes(user_grid, self.dtype)
+            qs = abft.lite_probe_axes(user_grid, dtype)
             # cotangent: the rank-1 probe over the user grid, zero-padded
             # into the padded output shape (probing the CROPPED output --
             # corruption confined to cropped-away padding cannot reach
@@ -539,14 +591,14 @@ class DistributedPoissonSolver:
                 r_user
             r = jax.device_put(r_pad, sh)
             zero = jax.device_put(
-                np.zeros(fp_shape, jnp.dtype(self.dtype)), sh)
+                np.zeros(fp_shape, jnp.dtype(dtype)), sh)
             base = self._build_jit(self.comm, donate=False,
                                    local_batch=local_batch)
             try:
                 with faults.suppressed():
                     w = jax.jit(lambda rr, gg, z: jax.vjp(
                         lambda x: base(x, gg), z)[1](rr)[0])(
-                            r, self.green_device(), zero)
+                            r, self.green_device(dtype), zero)
                     jax.block_until_ready(w)
                 # padding is zeros, so <w, pad(f)> == <w_valid, f>: keep
                 # only the valid corner, in the solve dtype -- the host
@@ -585,7 +637,7 @@ class DistributedPoissonSolver:
             dirs[0].layout.name,
             tuple((a, int(self.mesh.shape[a])) for a in self.mesh.axis_names),
             tuple(self.axes), self.batch_axis,
-            jnp.dtype(self.dtype).name, eng,
+            self._solve_dtype().name, eng,
             ("doubling", self.plan.doubling),
             # the layout schedule changes what every candidate compiles to
             # (relayouts folded into the switches vs standalone moveaxis,
@@ -610,7 +662,7 @@ class DistributedPoissonSolver:
 
         def time_cfg(cfg):
             fn = self._build_jit(cfg, donate=False, local_batch=local_batch)
-            f = jax.device_put(jnp.ones(fshape, self.dtype),
+            f = jax.device_put(jnp.ones(fshape, self._solve_dtype()),
                                NamedSharding(self.mesh, in_spec))
             # lazy_green dry-runs autotune against a zero kernel: comm cost
             # does not depend on the Green's values, only its layout
@@ -660,7 +712,7 @@ class DistributedPoissonSolver:
                 p2 = self.mesh.shape[self.axes[1]]
                 in_block = batch if self.batch_axis is None else None
                 candidates = guided_comm_candidates(
-                    self.plan, p1, p2, self.dtype, batch=in_block,
+                    self.plan, p1, p2, self._solve_dtype(), batch=in_block,
                     folds=folds, relayout=self.relayout,
                     max_radix=self.engine.max_radix,
                     census=self.autotune_census)
@@ -699,16 +751,20 @@ class DistributedPoissonSolver:
         f = _pad_dim(f, d2 + off, self._PU2)
         return f
 
-    def green_device(self):
-        if self._green_dev is None:
+    def green_device(self, dtype=None):
+        """The Green's function on the mesh for solves in ``dtype`` (default:
+        the solver's), put once per dtype."""
+        gd = np.dtype(_green_dtype(self._solve_dtype(dtype)))
+        g = self._green_dev.get(gd)
+        if g is None:
             with spans.span("flups.green.put"):
-                self._green_dev = jax.device_put(
-                    self._green_np,
+                g = self._green_dev[gd] = jax.device_put(
+                    self._green_np.astype(gd, copy=False),
                     NamedSharding(self.mesh, self.g_spec))
-        return self._green_dev
+        return g
 
     def _dispatch(self, f, local_batch: bool, abft: bool = False,
-                  lite: bool = False):
+                  lite: bool = False, consume: bool = False):
         """One solve attempt under the CURRENT config: pad, shard, run the
         jitted pipeline, crop.  Re-entered by the degradation ladder after
         ``_configure`` rebuilds -- padded extents/specs may differ per rung,
@@ -716,27 +772,34 @@ class DistributedPoissonSolver:
         ``abft`` the checked jit runs and ``(u, names, report)`` returns;
         under ``lite`` the SAME jit as verify-off runs (the sandwich is
         entirely host-side) and ``(u, qs, w_valid, w_norm)`` returns (or
-        None when the sandwich is unavailable for this config)."""
+        None when the sandwich is unavailable for this config).
+
+        The solve donates the buffer it places.  Under ``consume`` a
+        committed input already in the pencil's blocks is that buffer: the
+        solve consumes it.  Otherwise, and for any other input, the input
+        is left as it was and is placed as a copy."""
         with spans.span("flups.pad") as sp:
             sharding = NamedSharding(self.mesh, self.input_spec(local_batch))
             route = self._route(f, sharding)
             if route == "device":
                 fp = self._place_for(f.shape, f.sharding, local_batch)(f)
             else:
-                fp = jax.device_put(self._pad_input(f), sharding)
+                fp = jax.device_put(
+                    self._pad_input(f), sharding,
+                    may_alias=None if consume and f.committed else False)
             self.stats["placements"][route] += 1
             if sp.on:
                 sp.set("route", route)
         names = rep = None
         if lite:
-            ent = self._lite_pair(fp.shape, local_batch)
+            ent = self._lite_pair(fp.shape, f.dtype, local_batch)
             if ent is None:
                 return None
             qs, wv, wn = ent
-        green = self.green_device()
+        green = self.green_device(f.dtype)
         with spans.span("flups.launch") as sp:
             if abft:
-                fn, names = self.abft_jit_for(local_batch)
+                fn, names = self.abft_jit_for(local_batch, f.dtype)
                 out, rep = fn(fp, green)
             else:
                 out = self.jit_for(local_batch)(fp, green)
@@ -745,7 +808,7 @@ class DistributedPoissonSolver:
                 sp.set("switch_bytes", [s["bytes"] for s in sw])
                 sp.set("switch_live_bytes", [s["live_bytes"] for s in sw])
         with spans.span("flups.crop") as sp:
-            crop, gather = self._crop_for(out.shape, local_batch)
+            crop, gather = self._crop_for(out.shape, local_batch, out.dtype)
             if crop is not None:
                 out = crop(out)
             if sp.on and gather:
@@ -802,8 +865,9 @@ class DistributedPoissonSolver:
         out = _crop_dim(out, d2 + off, self._U[d2])
         return crop_doubling(out, self.plan.dirs)
 
-    def _crop_for(self, shape, local_batch: bool):
-        """``(crop, gather)`` for a padded output of ``shape``.  ``crop`` is
+    def _crop_for(self, shape, local_batch: bool, dtype=None):
+        """``(crop, gather)`` for a padded output of ``shape`` and ``dtype``
+        (default: the solver's).  ``crop`` is
         one jitted program under the scope ``crop``, or None when there is
         nothing to cut.  A sharded axis cut to a length its ranks cannot
         split evenly (JAX holds no uneven sharding) is first gathered onto
@@ -811,13 +875,14 @@ class DistributedPoissonSolver:
         gather's collectives carry the scope.  ``gather`` describes that
         gather, or is None without one: whether the output leaves the
         solve replicated, and the most bytes a chip receives."""
-        key = (tuple(shape), bool(local_batch))
+        dtype = self._solve_dtype(dtype)
+        key = (tuple(shape), dtype, bool(local_batch))
         if key in self._crops:
             return self._crops[key]
         spec = tuple(self.input_spec(local_batch))
         spec += (None,) * (len(shape) - len(spec))
         cut = jax.eval_shape(self._crop_output,
-                             jax.ShapeDtypeStruct(shape, self.dtype)).shape
+                             jax.ShapeDtypeStruct(shape, dtype)).shape
         if cut == tuple(shape):
             self._crops[key] = (None, None)
             return self._crops[key]
@@ -838,7 +903,7 @@ class DistributedPoissonSolver:
             # a chip's block before the gather lies inside its block after
             got = max(size(post[d]) - size(pre[d]) for d in pre)
             gather = {"replicated": constraint.is_fully_replicated,
-                      "bytes": got * jnp.dtype(self.dtype).itemsize}
+                      "bytes": got * dtype.itemsize}
 
         def crop(x):
             with jax.named_scope("crop"):
@@ -897,18 +962,25 @@ class DistributedPoissonSolver:
     def _run_solve(self, f, verify):
         from repro.runtime import abft as _abft
         from repro.runtime import faults, health, resilience
-        f_host = f if (isinstance(f, np.ndarray)
-                       and f.dtype == np.dtype(self.dtype)) else None
-        f = jnp.asarray(f, dtype=self.dtype)
+        f_in = f
+        f = jnp.asarray(f, dtype=self.dtype)   # None: the input's dtype
+        f_host = f_in if (isinstance(f_in, np.ndarray)
+                          and f_in.dtype == f.dtype) else None
         base = 3 + (1 if self.batch_axis is not None else 0)
-        assert f.ndim in (base, base + 1), (f.shape, base)
+        assert (f.ndim in (base, base + 1)
+                and f.shape[-3:] == self.input_shape), (f.shape, base)
         local_batch = f.ndim == base + 1
         verify = self.verify if verify is None else verify
+        # a solve on a given mesh with no health check consumes a committed
+        # input already in the pencil's blocks (it is not read again); a
+        # solver built without a mesh, like any checked solve, leaves the
+        # caller's input whole: the checks read it after the launch
+        consume = self._caller_mesh is not None and not verify
 
         def checked():
             out, names, rep = self._dispatch(f, local_batch, abft=True)
             _abft.verify_report(
-                list(names), np.asarray(rep), tol=self._abft_tol(),
+                list(names), np.asarray(rep), tol=self._abft_tol(f.dtype),
                 stats=self.stats, describe="dist.solve")
             return out
 
@@ -943,7 +1015,7 @@ class DistributedPoissonSolver:
                 # each device buffer -- skips the (slow) full-array gather
                 a = self._lite_contract(out, qs)
                 a = a.reshape(np.shape(b))
-                tol = self._abft_tol() * _abft.LITE_HEADROOM
+                tol = self._abft_tol(f.dtype) * _abft.LITE_HEADROOM
                 m = _abft.lite_mismatch_ab(a, b, np.zeros_like(wn))
                 if m > tol:
                     # near-cancelling dots: only now pay for the noise
@@ -963,7 +1035,7 @@ class DistributedPoissonSolver:
                     "mismatch": float(m), "tol": float(tol),
                     "action": "localize", "describe": "dist.solve"})
                 return checked()
-            out = self._dispatch(f, local_batch)
+            out = self._dispatch(f, local_batch, consume=consume)
             if verify:
                 locate = None
                 if not self._ctor["lazy_green"]:
@@ -1004,7 +1076,8 @@ class DistributedPoissonSolver:
             mesh=mesh, axes=tuple(axes) if axes is not None else self.axes,
             comm=comm if comm is not None else c["comm_req"],
             batch_axis=self.batch_axis, eps_factor=c["eps_factor"],
-            dtype=self.dtype, lazy_green=c["lazy_green"],
+            dtype=_DTYPE_DEFAULT if self.dtype is None else self.dtype,
+            lazy_green=c["lazy_green"],
             engine=(c["engine_obj"]
                     if c["engine_obj"].name == self._cfg["engine"]
                     else self._cfg["engine"]),
@@ -1029,8 +1102,9 @@ class DistributedPoissonSolver:
         in-block multi-RHS dim under ``local_batch=True``), or a
         ``(pod, local)`` pair when both are present.  Missing leading dims
         default to 1 so the lowered rank always matches the input spec.
+        ``dtype`` is the input's; a solver with a dtype casts to its own.
         """
-        dtype = dtype or self.dtype
+        dtype = self._solve_dtype(dtype)
         defaults = []           # leading dims in order: pod-sharded, local
         if self.batch_axis is not None:
             defaults.append(int(self.mesh.shape[self.batch_axis]))
@@ -1046,11 +1120,11 @@ class DistributedPoissonSolver:
         spec = self.input_spec(local_batch)
         f = jax.ShapeDtypeStruct(shp, dtype,
                                  sharding=NamedSharding(self.mesh, spec))
-        return self.jit_for(local_batch).lower(f, self._green_shape())
+        return self.jit_for(local_batch).lower(f, self._green_shape(dtype))
 
-    def _green_shape(self):
+    def _green_shape(self, dtype=None):
         return jax.ShapeDtypeStruct(
-            self._green_np.shape, self._green_np.dtype,
+            self._green_np.shape, _green_dtype(self._solve_dtype(dtype)),
             sharding=NamedSharding(self.mesh, self.g_spec))
 
     def stage_map(self) -> dict:
@@ -1059,7 +1133,7 @@ class DistributedPoissonSolver:
         ``{"fwd.0": "pallas", ..., "green": "pallas", ...}``."""
         from repro.core.engine import stage_map
         f = jax.ShapeDtypeStruct(
-            self.padded_input_shape(), self.dtype,
+            self.padded_input_shape(), self._solve_dtype(),
             sharding=NamedSharding(self.mesh, self.input_spec()))
         return stage_map(jax.make_jaxpr(self.jit_for())(
             f, self._green_shape()))

@@ -10,8 +10,8 @@ them no-ops (one ``None`` check) when no plan is active:
     Raises ``InjectedFault`` when a raising spec (kind ``error``,
     ``pallas_lowering``, ``device_loss`` or ``torn_write``) matches the
     hook's stage name.  Hooks sit at trace/dispatch boundaries
-    (``solve.dispatch``, ``dist.dispatch``, ``pallas.fwd.<d>``,
-    ``comm.<strategy>``, ``ckpt.leaf.<i>``), so an armed spec simulates a
+    (``dist.dispatch``, ``pallas.fwd.<d>``, ``comm.<strategy>``,
+    ``ckpt.leaf.<i>``), so an armed spec simulates a
     kernel failing at lowering, a collective dying, or a checkpoint write
     torn mid-leaf -- deterministically, at the same point every run.
 

@@ -23,9 +23,9 @@ The paper's dominant production operation -- the unbounded Poisson solve
   cold keys are evicted (also from the module LRU) and rebuild on the
   next request through ``get_solver``'s single-flight path.
 * **Resilience**: every batched solve runs under the PR-6 degradation
-  ladder (``PoissonSolver.solve`` -> ``run_with_ladder``).  Ladder
-  records produced by a batch are attributed to every request in it and
-  surface per tenant in ``tenant_stats()``.  A request may carry its own
+  ladder (``DistributedPoissonSolver.solve`` -> ``run_with_ladder``).
+  Ladder records produced by a batch are attributed to every request in
+  it and surface per tenant in ``tenant_stats()``.  A request may carry its own
   ``FaultPlan`` (chaos testing): it is armed around that batch's solve
   only, and because the fault token is part of the ``get_solver`` key the
   armed batch runs on a shadow solver -- the clean warm plan's jit caches
@@ -75,8 +75,8 @@ class PlanSpec:
     """The serving identity of a solve: everything that selects a plan.
 
     Mirrors the ``get_solver`` signature; two requests coalesce into one
-    batched solve iff their specs freeze to the same key.  ``mesh`` makes
-    the spec distributed (a pencil solver on that mesh); ``solver_kw``
+    batched solve iff their specs freeze to the same key.  ``mesh`` is the
+    solver's device mesh (None: one device); ``solver_kw``
     passes through extra ``get_solver`` keywords (``comm``, ``dtype``,
     autotune knobs, ...) as a tuple of (name, value) pairs.
     """
@@ -93,7 +93,7 @@ class PlanSpec:
     order_policy: str = "layout"
     mesh: object = None
     solver_kw: tuple = ()
-    # comm="auto" candidate policy on distributed specs (DESIGN.md #12):
+    # comm="auto" candidate policy (DESIGN.md #12):
     # "guided" warms the pool off the cost-model shortlist, "brute" sweeps
     search: str = "guided"
 
@@ -105,7 +105,9 @@ class PlanSpec:
 
     def build(self):
         kw = dict(self.solver_kw)
-        if self.mesh is not None:
+        # only a policy other than the solver's own default joins the
+        # get_solver key: a spec and a direct call share one solver
+        if self.search != "guided":
             kw.setdefault("autotune_search", self.search)
         return sv.get_solver(self.shape, self.L, self.bcs,
                              layout=self.layout, green_kind=self.green_kind,

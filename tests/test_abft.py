@@ -37,7 +37,7 @@ import pytest
 
 from repro.ckpt import checkpoint as ck
 from repro.core.bc import BCType, DataLayout
-from repro.core.solver import PoissonSolver
+from repro.distributed.pencil import DistributedPoissonSolver
 from repro.runtime import abft, faults
 from repro.runtime.resilience import SolveError
 
@@ -136,7 +136,7 @@ def test_wire_checksums_catch_slab_corruption():
     assert ei.value.stage == "verify.abft@wire.comm.test"
 
 
-# -- detection matrix (single process) ---------------------------------------
+# -- detection matrix (one device) -------------------------------------------
 
 STAGES = ["fwd.0", "fwd.1", "fwd.2", "green", "bwd.0", "bwd.1", "bwd.2"]
 
@@ -151,13 +151,13 @@ def _chaos_trial(stage, *, relayout="scheduled", layout=DataLayout.CELL,
     healthy output differs from the plain solve at roundoff -- "repaired"
     means the recompute restored exactly what the unfaulted checked
     pipeline produces."""
-    s0 = PoissonSolver((12, 12, 12), 1.0, BCS, layout=layout,
-                       engine="xla", relayout=relayout)
+    s0 = DistributedPoissonSolver((12, 12, 12), 1.0, BCS, layout=layout,
+                                  engine="xla", relayout=relayout)
     shape = ((2,) + s0.input_shape) if batched else s0.input_shape
     f = _rhs(shape, seed=7)
     want = np.asarray(s0.solve(f, verify=verify))
-    s = PoissonSolver((12, 12, 12), 1.0, BCS, layout=layout, engine="xla",
-                      relayout=relayout)
+    s = DistributedPoissonSolver((12, 12, 12), 1.0, BCS, layout=layout,
+                                 engine="xla", relayout=relayout)
     with faults.FaultPlan([dict(kind="flip", stage=stage,
                                 count=count)]) as plan:
         got = np.asarray(s.solve(f, verify=verify))
@@ -207,12 +207,13 @@ def test_two_phase_guard_localizes_then_repairs():
     trips it (hit 1 lands in the sandwich trace), the checked re-dispatch
     localizes the stage (hit 2), and the inline recompute repairs it --
     transient SDC never reaches the degradation ladder."""
-    s0 = PoissonSolver((12, 12, 12), 1.0, BCS, engine="xla")
+    s0 = DistributedPoissonSolver((12, 12, 12), 1.0, BCS, engine="xla")
     f = _rhs(s0.input_shape)
     # after the trip the answer comes from the checked re-dispatch, so
     # the bit-exact baseline is the clean CHECKED pipeline's output
     want = np.asarray(s0.solve(f, verify="abft-stages"))
-    s = PoissonSolver((12, 12, 12), 1.0, BCS, engine="xla", verify="abft")
+    s = DistributedPoissonSolver((12, 12, 12), 1.0, BCS, engine="xla",
+                                 verify="abft")
     with faults.FaultPlan([dict(kind="flip", stage="fwd.1",
                                 count=2)]) as plan:
         got = np.asarray(s.solve(f))
@@ -234,9 +235,9 @@ def test_clean_soak_zero_false_positives():
     integrity record or verify failure may appear (tolerances must sit
     above the roundoff of every healthy config)."""
     for verify in ("abft", "abft-stages"):
-        s = PoissonSolver((16, 16, 16), 1.0, BCS, engine="xla",
-                          verify=verify)
-        ref = PoissonSolver((16, 16, 16), 1.0, BCS, engine="xla")
+        s = DistributedPoissonSolver((16, 16, 16), 1.0, BCS, engine="xla",
+                                     verify=verify)
+        ref = DistributedPoissonSolver((16, 16, 16), 1.0, BCS, engine="xla")
         for seed in range(8):
             f = _rhs(s.input_shape, seed=seed)
             got = np.asarray(s.solve(f))
@@ -252,8 +253,8 @@ def test_persistent_corruption_escalates_to_solve_error():
     ladder rung's retrace -- the guard must escalate to a structured
     ``SolveError`` with ABFT stage provenance, never return silently
     corrupted output."""
-    s = PoissonSolver((12, 12, 12), 1.0, BCS, engine="xla",
-                      verify="abft-stages")
+    s = DistributedPoissonSolver((12, 12, 12), 1.0, BCS, engine="xla",
+                                 verify="abft-stages")
     f = _rhs(s.input_shape)
     with faults.FaultPlan([dict(kind="flip", stage="green", count=-1)]):
         with pytest.raises(SolveError) as ei:

@@ -21,10 +21,10 @@ except ImportError:  # CI images without hypothesis: deterministic local shim
 from repro.core.bc import BCType, DataLayout
 from repro.core.biot_savart import BiotSavartSolver
 from repro.core.green import GreenKind
-from repro.core import solver as sv
-from repro.core.solver import (PoissonSolver, get_solver,
-                               clear_solver_cache, solver_cache_info,
-                               set_solver_cache_capacity)
+from repro.core.solver import (get_solver, clear_solver_cache,
+                               solver_cache_info, set_solver_cache_capacity)
+from repro.distributed import pencil
+from repro.distributed.pencil import DistributedPoissonSolver
 
 E, O, P, U = BCType.EVEN, BCType.ODD, BCType.PER, BCType.UNB
 
@@ -78,7 +78,7 @@ def test_batched_solve_equals_stacked_pallas(b, bc0, layout, seed):
 
 
 def test_batched_rejects_bad_ranks():
-    s = PoissonSolver((8, 8, 8), 1.0, ((E, E), (E, E), (E, E)))
+    s = DistributedPoissonSolver((8, 8, 8), 1.0, ((E, E), (E, E), (E, E)))
     with pytest.raises(AssertionError):
         s.solve(np.zeros((8, 8)))               # rank too low
     with pytest.raises(AssertionError):
@@ -187,7 +187,7 @@ def test_plan_cache_solver_still_correct(fresh_cache):
     bcs = ((E, E), (O, E), (P, P))
     s_cached = get_solver((8, 8, 8), 1.0, bcs)
     s_cached2 = get_solver((8, 8, 8), 1.0, bcs)
-    fresh = PoissonSolver((8, 8, 8), 1.0, bcs)
+    fresh = DistributedPoissonSolver((8, 8, 8), 1.0, bcs)
     rng = np.random.default_rng(3)
     f = rng.standard_normal(fresh.input_shape)
     np.testing.assert_allclose(np.asarray(s_cached2.solve(f)),
@@ -210,14 +210,14 @@ def test_single_flight_one_construction_per_key(fresh_cache, monkeypatch):
 
     built = []
     build_gate = threading.Barrier(16, timeout=60)
-    real = sv.PoissonSolver
+    real = pencil.DistributedPoissonSolver
 
     class Counting(real):
         def __init__(self, *a, **kw):
             built.append(threading.get_ident())
             super().__init__(*a, **kw)
 
-    monkeypatch.setattr(sv, "PoissonSolver", Counting)
+    monkeypatch.setattr(pencil, "DistributedPoissonSolver", Counting)
     bcs = ((E, E), (O, E), (P, P))
     out, errors = [], []
 
@@ -251,7 +251,7 @@ def test_single_flight_failed_build_reraises_everywhere(fresh_cache,
     import threading
 
     calls = []
-    real = sv.PoissonSolver
+    real = pencil.DistributedPoissonSolver
 
     class Flaky(real):
         def __init__(self, *a, **kw):
@@ -260,7 +260,7 @@ def test_single_flight_failed_build_reraises_everywhere(fresh_cache,
                 raise RuntimeError("flaky plan-time failure")
             super().__init__(*a, **kw)
 
-    monkeypatch.setattr(sv, "PoissonSolver", Flaky)
+    monkeypatch.setattr(pencil, "DistributedPoissonSolver", Flaky)
     bcs = ((E, E), (E, E), (E, E))
     gate = threading.Barrier(4, timeout=60)
     failures = []

@@ -1,4 +1,5 @@
-"""Distributed pencil solver == reference solver, for all comm strategies.
+"""Pencil solve on a 2x4 mesh == the plain natural-layout reference solve
+(``tests/_reference.py``), for all comm strategies.
 
 Runs in subprocesses with XLA_FLAGS=--xla_force_host_platform_device_count=8
 so the main test session keeps seeing a single device.  Covers the four
@@ -23,8 +24,10 @@ import jax.numpy as jnp
 from repro.core.bc import BCType, DataLayout
 from repro.core.comm import CommConfig
 from repro.core.green import GreenKind
-from repro.core.solver import PoissonSolver
+from repro.core.solver import make_plan
 from repro.distributed.pencil import DistributedPoissonSolver
+sys.path.insert(0, "tests")
+from _reference import reference_solve
 
 E, O, P, U = BCType.EVEN, BCType.ODD, BCType.PER, BCType.UNB
 cfg = json.loads(sys.argv[1])
@@ -33,11 +36,10 @@ layout = DataLayout[cfg["layout"]]
 n = cfg["n"]
 mesh = jax.make_mesh((2, 4), ("data", "model"))
 
-ref = PoissonSolver((n, n, n), 1.0, bcs, layout=layout,
-                    green_kind=cfg["green"])
+shape = make_plan((n, n, n), 1.0, bcs, layout, cfg["green"]).input_shape
 rng = np.random.default_rng(0)
-f = rng.standard_normal(ref.input_shape)
-want = np.asarray(ref.solve(jnp.asarray(f)))
+f = rng.standard_normal(shape)
+want = reference_solve(f, (n, n, n), 1.0, bcs, layout, cfg["green"])
 
 for strategy in ("a2a", "pipelined", "fused", "overlap"):
     ds = DistributedPoissonSolver(

@@ -15,7 +15,10 @@ from repro.core.analytic import CASES
 from repro.core.bc import BCType, DataLayout
 from repro.core.engine import (TransformEngine, as_engine, build_schedule)
 from repro.core.green import GreenKind
-from repro.core.solver import PoissonSolver, make_plan
+from repro.core.solver import make_plan
+from repro.distributed.pencil import DistributedPoissonSolver
+
+from _reference import reference_solve
 
 E, O, P, U = BCType.EVEN, BCType.ODD, BCType.PER, BCType.UNB
 
@@ -41,6 +44,23 @@ def test_schedule_folds_all_normfacts():
     assert sched.fwd_tables[0] is not None
 
 
+def _program(s, dtype):
+    """The solver's jitted program for a ``dtype`` input, traced."""
+    f = jax.ShapeDtypeStruct(s.padded_input_shape(), dtype)
+    return jax.make_jaxpr(s.jit_for())(f, s._green_shape(dtype))
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr``, sub-jaxprs included."""
+    for eq in jaxpr.eqns:
+        yield eq
+        for v in eq.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
 @pytest.mark.parametrize("case,layout", [
     ("A", DataLayout.CELL), ("A", DataLayout.NODE)])
 def test_engines_match_on_mixed_bc_solve(case, layout):
@@ -49,8 +69,9 @@ def test_engines_match_on_mixed_bc_solve(case, layout):
     n = 32
     rhs, _ = fn(n, layout)
     kw = dict(layout=layout, green_kind=GreenKind.CHAT2)
-    sx = PoissonSolver((n, n, n), 1.0, bcs, engine="xla", **kw)
-    sp = PoissonSolver((n, n, n), 1.0, bcs, engine="pallas", **kw)
+    sx = DistributedPoissonSolver((n, n, n), 1.0, bcs, engine="xla", **kw)
+    sp = DistributedPoissonSolver((n, n, n), 1.0, bcs, engine="pallas",
+                                  **kw)
     ux = np.asarray(sx.solve(rhs.astype(np.float64)))
     up = np.asarray(sp.solve(rhs.astype(np.float64)))
     np.testing.assert_allclose(up, ux, rtol=1e-5, atol=1e-5)
@@ -65,8 +86,9 @@ def test_engines_match_on_unbounded_solve():
     n = 16
     rhs, _ = fn(n, DataLayout.CELL)
     kw = dict(layout=DataLayout.CELL, green_kind=GreenKind.CHAT2)
-    sx = PoissonSolver((n, n, n), 1.0, bcs, engine="xla", **kw)
-    sp = PoissonSolver((n, n, n), 1.0, bcs, engine="pallas", **kw)
+    sx = DistributedPoissonSolver((n, n, n), 1.0, bcs, engine="xla", **kw)
+    sp = DistributedPoissonSolver((n, n, n), 1.0, bcs, engine="pallas",
+                                  **kw)
     ux = np.asarray(sx.solve(rhs.astype(np.float64)))
     up = np.asarray(sp.solve(rhs.astype(np.float64)))
     np.testing.assert_allclose(up, ux, rtol=1e-5, atol=1e-5)
@@ -75,14 +97,14 @@ def test_engines_match_on_unbounded_solve():
 def test_pallas_engine_actually_uses_kernels():
     """The pallas engine must put pallas_call ops in the traced solve."""
     n = 16
-    s = PoissonSolver((n, n, n), 1.0, ((E, E), (O, E), (P, P)),
-                      layout=DataLayout.CELL, engine="pallas")
-    f = jnp.zeros(s.input_shape)
-    trace = str(jax.make_jaxpr(s._solve_impl)(f))
+    s = DistributedPoissonSolver((n, n, n), 1.0, ((E, E), (O, E), (P, P)),
+                                 layout=DataLayout.CELL, engine="pallas")
+    dtype = jnp.zeros(()).dtype
+    trace = str(_program(s, dtype))
     assert "pallas_call" in trace
-    sx = PoissonSolver((n, n, n), 1.0, ((E, E), (O, E), (P, P)),
-                       layout=DataLayout.CELL, engine="xla")
-    assert "pallas_call" not in str(jax.make_jaxpr(sx._solve_impl)(f))
+    sx = DistributedPoissonSolver((n, n, n), 1.0, ((E, E), (O, E), (P, P)),
+                                  layout=DataLayout.CELL, engine="xla")
+    assert "pallas_call" not in str(_program(sx, dtype))
 
 
 def test_zero_standalone_normalization_multiplies():
@@ -90,12 +112,11 @@ def test_zero_standalone_normalization_multiplies():
     in the jaxpr is the fused Green multiply -- every per-direction
     normfact pass of the seed implementation is gone."""
     n = 16
-    s = PoissonSolver((n, n, n), 1.0, ((E, E), (E, E), (E, E)),
-                      layout=DataLayout.NODE, engine="xla")
-    f = jnp.zeros(s.input_shape)
-    jaxpr = jax.make_jaxpr(s._solve_impl)(f)
+    s = DistributedPoissonSolver((n, n, n), 1.0, ((E, E), (E, E), (E, E)),
+                                 layout=DataLayout.NODE, engine="xla")
+    jaxpr = _program(s, jnp.zeros(()).dtype)
     float_muls = [
-        eq for eq in jaxpr.jaxpr.eqns
+        eq for eq in _eqns(jaxpr.jaxpr)
         if eq.primitive.name == "mul"
         and any(jnp.issubdtype(v.aval.dtype, jnp.inexact)
                 for v in eq.invars if hasattr(v, "aval"))
@@ -145,20 +166,19 @@ def test_distributed_engines_match():
 
 
 def test_distributed_matches_reference_with_pallas_engine():
-    """Pallas-engine distributed solve still matches the single-process
-    reference solver (mixed-BC validation of tests/test_poisson.py)."""
-    from repro.distributed.pencil import DistributedPoissonSolver
+    """Pallas-engine distributed solve still matches the plain
+    natural-layout reference solve (mixed-BC validation of
+    tests/test_poisson.py)."""
     mesh = jax.make_mesh((1, 1), ("data", "model"))
     fn, bcs = CASES["A"]
     n = 16
     layout = DataLayout.CELL
     rhs, _ = fn(n, layout)
-    ref = PoissonSolver((n, n, n), 1.0, bcs, layout=layout,
-                        green_kind=GreenKind.CHAT2, engine="xla")
     ds = DistributedPoissonSolver(
         (n, n, n), 1.0, bcs, layout=layout, green_kind=GreenKind.CHAT2,
         mesh=mesh, dtype=jnp.float64, engine="pallas")
-    want = np.asarray(ref.solve(rhs.astype(np.float64)))
+    want = reference_solve(rhs.astype(np.float64), (n, n, n), 1.0, bcs,
+                           layout, GreenKind.CHAT2)
     got = np.asarray(ds.solve(rhs))
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
     assert ds.stats["degradations"] == [] and ds.stats["retries"] == 0

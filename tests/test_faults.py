@@ -19,7 +19,7 @@ import pytest
 
 from repro.core.bc import BCType
 from repro.core.comm import CommConfig, autotune_comm, clear_autotune_cache
-from repro.core.solver import PoissonSolver
+from repro.distributed.pencil import DistributedPoissonSolver
 from repro.ckpt import checkpoint as ck
 from repro.runtime import faults, health, resilience
 from repro.runtime.resilience import SolveError
@@ -175,7 +175,7 @@ def test_retry_seed_env_override(monkeypatch):
                                                      seed=7))
 
 
-# -- solver-level recovery (single process, bit-exact) -----------------------
+# -- solver-level recovery (one device, bit-exact) ---------------------------
 
 def _rhs(shape, seed=0):
     rng = np.random.default_rng(seed)
@@ -183,10 +183,11 @@ def _rhs(shape, seed=0):
 
 
 def test_nan_injection_recovers_bit_exact():
-    s0 = PoissonSolver((12, 12, 12), 1.0, BCS, engine="xla")
+    s0 = DistributedPoissonSolver((12, 12, 12), 1.0, BCS, engine="xla")
     f = _rhs(s0.input_shape)
     want = np.asarray(s0.solve(f))
-    s = PoissonSolver((12, 12, 12), 1.0, BCS, engine="xla", verify="nan")
+    s = DistributedPoissonSolver((12, 12, 12), 1.0, BCS, engine="xla",
+                                 verify="nan")
     with faults.FaultPlan([dict(kind="nan", stage="green")]) as plan:
         got = np.asarray(s.solve(f))
     assert plan.log, "fault never fired"
@@ -198,10 +199,10 @@ def test_nan_injection_recovers_bit_exact():
 
 def test_pallas_lowering_failure_degrades_to_xla():
     want = None
-    sx = PoissonSolver((12, 12, 12), 1.0, BCS, engine="xla")
+    sx = DistributedPoissonSolver((12, 12, 12), 1.0, BCS, engine="xla")
     f = _rhs(sx.input_shape)
     want = np.asarray(sx.solve(f))
-    sp = PoissonSolver((12, 12, 12), 1.0, BCS, engine="pallas")
+    sp = DistributedPoissonSolver((12, 12, 12), 1.0, BCS, engine="pallas")
     with faults.FaultPlan([dict(kind="pallas_lowering", stage="pallas.*",
                                 count=-1)]):
         got = np.asarray(sp.solve(f))
@@ -219,12 +220,14 @@ def test_residual_verify_passes_healthy_and_catches_corruption():
     sol = np.sin(2 * np.pi * x) * np.sin(4 * np.pi * y) * \
         np.cos(2 * np.pi * z)
     rhs = (-(4 + 16 + 4) * np.pi ** 2 * sol).astype(np.float64)
-    s = PoissonSolver((n, n, n), 1.0, ((P, P),) * 3, verify="residual")
+    s = DistributedPoissonSolver((n, n, n), 1.0, ((P, P),) * 3,
+                                 verify="residual")
     s.solve(rhs)
     assert s.stats["last_residual"] < 0.05
     # a corrupted (inf) green multiply must trip the residual/nan guard and
     # recover down the ladder to the same bits as a fault-free solve
-    want = np.asarray(PoissonSolver((n, n, n), 1.0, ((P, P),) * 3).solve(rhs))
+    want = np.asarray(DistributedPoissonSolver(
+        (n, n, n), 1.0, ((P, P),) * 3).solve(rhs))
     with faults.FaultPlan([dict(kind="inf", stage="green")]):
         got = np.asarray(s.solve(rhs))
     assert s.stats["verify_failures"] == 1
@@ -232,14 +235,14 @@ def test_residual_verify_passes_healthy_and_catches_corruption():
 
 
 def test_hard_fault_raises_structured_solve_error():
-    s = PoissonSolver((8, 8, 8), 1.0, BCS)
+    s = DistributedPoissonSolver((8, 8, 8), 1.0, BCS)
     f = _rhs(s.input_shape)
-    with faults.FaultPlan([dict(kind="error", stage="solve.dispatch",
+    with faults.FaultPlan([dict(kind="error", stage="dist.dispatch",
                                 count=-1)]):
         with pytest.raises(SolveError) as ei:
             s.solve(f)
     e = ei.value
-    assert e.stage == "solve.dispatch"
+    assert e.stage == "dist.dispatch"
     assert [d["action"] for d in e.degradations] == \
         ["relayout:scheduled->baseline", "doubling:deferred->upfront"]
     assert e.config["doubling"] == "upfront"

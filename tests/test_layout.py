@@ -36,7 +36,8 @@ except ImportError:
 from repro.core.bc import BCType, DataLayout
 from repro.core.engine import (LayoutSchedule, build_schedule, relayout,
                                schedule_layouts, switch_layout, to_last)
-from repro.core.solver import PoissonSolver, make_plan
+from repro.core.solver import make_plan
+from repro.distributed.pencil import DistributedPoissonSolver
 
 U, P, E, O = BCType.UNB, BCType.PER, BCType.EVEN, BCType.ODD
 
@@ -103,13 +104,14 @@ def test_r2c_follows_the_scheduled_order():
     assert all(plan.dirs[d].dft == "c2c" for d in plan.order[1:])
 
 
-# -- scheduled == baseline, single process ----------------------------------
+# -- scheduled == baseline, one device --------------------------------------
 
 def _solvers(cats, layout, engine, doubling="deferred", n=4):
     bcs = tuple(CATS[c] for c in cats)
     kw = dict(layout=layout, engine=engine, doubling=doubling)
-    a = PoissonSolver((n,) * 3, 1.0, bcs, relayout="scheduled", **kw)
-    b = PoissonSolver((n,) * 3, 1.0, bcs, relayout="baseline", **kw)
+    a = DistributedPoissonSolver((n,) * 3, 1.0, bcs, relayout="scheduled",
+                                 **kw)
+    b = DistributedPoissonSolver((n,) * 3, 1.0, bcs, relayout="baseline", **kw)
     return a, b
 
 
@@ -152,8 +154,8 @@ def test_order_policies_agree_to_roundoff():
     """order_policy="layout" (reordered execution) solves the same problem
     as the historical natural order to fp accuracy."""
     bcs = (CATS["unb"],) * 3
-    a = PoissonSolver((8,) * 3, 1.0, bcs)
-    b = PoissonSolver((8,) * 3, 1.0, bcs, order_policy="natural")
+    a = DistributedPoissonSolver((8,) * 3, 1.0, bcs)
+    b = DistributedPoissonSolver((8,) * 3, 1.0, bcs, order_policy="natural")
     assert a.plan.order != b.plan.order
     f = jnp.asarray(np.random.default_rng(0).standard_normal(a.input_shape))
     np.testing.assert_allclose(np.asarray(a.solve(f)),

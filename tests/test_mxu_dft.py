@@ -17,7 +17,8 @@ import jax.numpy as jnp
 from repro.core import transforms as tr
 from repro.core.bc import BCType, DataLayout
 from repro.core.engine import TransformEngine, build_schedule, stage_map
-from repro.core.solver import PoissonSolver, make_plan
+from repro.core.solver import make_plan
+from repro.distributed.pencil import DistributedPoissonSolver
 
 U, E, O = BCType.UNB, BCType.EVEN, BCType.ODD
 REL = 2e-6          # float32 rounding of a length <= 1024 product
@@ -136,6 +137,12 @@ def test_schedule_builds_matrices_for_routed_dft_directions():
     assert all(m is not None for m in sched.dft_mats[1:])
 
 
+def _program(s, dtype):
+    """The solver's jitted program for a ``dtype`` input, traced."""
+    f = jax.ShapeDtypeStruct(s.padded_input_shape(), dtype)
+    return jax.make_jaxpr(s.jit_for())(f, s._green_shape(dtype))
+
+
 def _dots(jaxpr, prefix=""):
     """(name stack, params) of every dot_general, sub-jaxprs included."""
     out = []
@@ -163,16 +170,18 @@ def test_tpu_engine_solve_routes_every_stage(monkeypatch, relayout,
     and every routed product runs at ``Precision.HIGHEST``."""
     monkeypatch.setattr(tr, "MXU_DFT_LENGTHS", (128,))
     kw = dict(layout=DataLayout.NODE, relayout=relayout, doubling=doubling)
-    mxu = PoissonSolver((64,) * 3, 1.0, ((U, U),) * 3,
-                        engine=TransformEngine("xla", platform="tpu"), **kw)
-    ref = PoissonSolver((64,) * 3, 1.0, ((U, U),) * 3,
-                        engine=TransformEngine("xla", platform="cpu"), **kw)
+    mxu = DistributedPoissonSolver(
+        (64,) * 3, 1.0, ((U, U),) * 3,
+        engine=TransformEngine("xla", platform="tpu"), **kw)
+    ref = DistributedPoissonSolver(
+        (64,) * 3, 1.0, ((U, U),) * 3,
+        engine=TransformEngine("xla", platform="cpu"), **kw)
     rng = np.random.default_rng(0)
     f = jnp.asarray(rng.standard_normal(mxu.input_shape), jnp.float32)
     got, want = np.asarray(mxu.solve(f)), np.asarray(ref.solve(f))
     assert _rel(got, want) <= 1e-5
 
-    jaxpr = jax.make_jaxpr(mxu._solve)(f)
+    jaxpr = _program(mxu, f.dtype)
     stages = stage_map(jaxpr)
     assert {k: v for k, v in stages.items() if k != "green"} == {
         f"{s}.{d}": "mxu" for s in ("fwd", "bwd") for d in range(3)}
@@ -183,15 +192,16 @@ def test_tpu_engine_solve_routes_every_stage(monkeypatch, relayout,
     hi = jax.lax.Precision.HIGHEST
     for p in routed:
         assert tuple(p["precision"]) == (hi, hi), p["precision"]
-    assert "xla" in set(stage_map(jax.make_jaxpr(ref._solve)(f)).values())
+    assert "xla" in set(stage_map(_program(ref, f.dtype)).values())
 
 
 def test_float64_solve_keeps_fft_on_a_tpu_engine(monkeypatch):
     """float64 data is not routed, even on a TPU engine: the stages read
     "xla" and the jaxpr keeps its FFTs."""
     monkeypatch.setattr(tr, "MXU_DFT_LENGTHS", (32,))
-    s = PoissonSolver((16,) * 3, 1.0, ((U, U),) * 3, layout=DataLayout.NODE,
-                      engine=TransformEngine("xla", platform="tpu"))
+    s = DistributedPoissonSolver((16,) * 3, 1.0, ((U, U),) * 3,
+                                 layout=DataLayout.NODE,
+                                 engine=TransformEngine("xla", platform="tpu"))
     f = jnp.zeros(s.input_shape, jnp.float64)
-    stages = stage_map(jax.make_jaxpr(s._solve)(f))
+    stages = stage_map(_program(s, f.dtype))
     assert set(stages.values()) == {"xla"}

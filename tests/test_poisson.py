@@ -15,14 +15,14 @@ import pytest
 from repro.core.analytic import CASES, L
 from repro.core.bc import DataLayout
 from repro.core.green import GreenKind
-from repro.core.solver import PoissonSolver
+from repro.distributed.pencil import DistributedPoissonSolver
 
 
 def linf_error(case, bcs, n, layout, green, eps_factor=2.0):
     fn, _ = CASES[case] if isinstance(case, str) else (case, None)
     rhs, sol = fn(n, layout)
-    s = PoissonSolver((n, n, n), L, bcs, layout=layout, green_kind=green,
-                      eps_factor=eps_factor)
+    s = DistributedPoissonSolver((n, n, n), L, bcs, layout=layout,
+                                 green_kind=green, eps_factor=eps_factor)
     u = np.asarray(s.solve(rhs.astype(np.float64)))
     return np.max(np.abs(u - sol))
 

@@ -30,7 +30,8 @@ except ImportError:
     from _hypothesis_shim import given, settings, strategies as st
 
 from repro.core.bc import BCType, DataLayout
-from repro.core.solver import PoissonSolver, make_plan
+from repro.core.solver import make_plan
+from repro.distributed.pencil import DistributedPoissonSolver
 
 U, P, E, O = BCType.UNB, BCType.PER, BCType.EVEN, BCType.ODD
 
@@ -45,10 +46,10 @@ CATS = {
 
 def _solvers(cats, layout, engine, n=4):
     bcs = tuple(CATS[c] for c in cats)
-    a = PoissonSolver((n,) * 3, 1.0, bcs, layout=layout, engine=engine,
-                      doubling="deferred")
-    b = PoissonSolver((n,) * 3, 1.0, bcs, layout=layout, engine=engine,
-                      doubling="upfront")
+    a = DistributedPoissonSolver((n,) * 3, 1.0, bcs, layout=layout,
+                                 engine=engine, doubling="deferred")
+    b = DistributedPoissonSolver((n,) * 3, 1.0, bcs, layout=layout,
+                                 engine=engine, doubling="upfront")
     return a, b
 
 
@@ -109,8 +110,8 @@ def test_pruned_engines_agree():
     """xla and pallas engines agree on a pruned all-unbounded solve (the
     pruned Stockham entry points against jnp.fft)."""
     bcs = (CATS["unb"],) * 3
-    sx = PoissonSolver((8,) * 3, 1.0, bcs, engine="xla")
-    sp = PoissonSolver((8,) * 3, 1.0, bcs, engine="pallas")
+    sx = DistributedPoissonSolver((8,) * 3, 1.0, bcs, engine="xla")
+    sp = DistributedPoissonSolver((8,) * 3, 1.0, bcs, engine="pallas")
     f = jnp.asarray(np.random.default_rng(3).standard_normal(sx.input_shape))
     np.testing.assert_allclose(np.asarray(sx.solve(f)),
                                np.asarray(sp.solve(f)),
@@ -199,7 +200,7 @@ def test_make_plan_rejects_unknown_doubling():
 # -- distributed equality + the comm-bytes acceptance probe -----------------
 
 _DIST_SCRIPT = r"""
-import os
+import os, sys
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import numpy as np
@@ -208,9 +209,10 @@ jax.config.update("jax_enable_x64", True)
 import jax.numpy as jnp
 from repro.core.bc import BCType
 from repro.core.comm import CommConfig
-from repro.core.solver import PoissonSolver
 from repro.distributed.pencil import DistributedPoissonSolver
 from repro.launch.hlo_stats import comm_bytes_stats
+sys.path.insert(0, "tests")
+from _reference import reference_solve
 
 U, P = (BCType.UNB, BCType.UNB), (BCType.PER, BCType.PER)
 mesh = jax.make_mesh((2, 4), ("data", "model"))
@@ -218,9 +220,8 @@ rng = np.random.default_rng(0)
 
 stats = {}
 for case, bcs in (("unb", (U, U, U)), ("per", (P, P, P))):
-    ref = PoissonSolver((16,) * 3, 1.0, bcs)
-    f = rng.standard_normal(ref.input_shape)
-    want = np.asarray(ref.solve(jnp.asarray(f)))
+    f = rng.standard_normal((16,) * 3)
+    want = reference_solve(f, (16,) * 3, 1.0, bcs)
     got = {}
     for doubling in ("deferred", "upfront"):
         ds = DistributedPoissonSolver(

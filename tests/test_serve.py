@@ -64,6 +64,8 @@ def test_coalesced_batch_bitexact_vs_individual():
         res = [f.result(timeout=120) for f in futs]
     assert any(r.batch_size > 1 for r in res), "nothing coalesced"
     s = get_solver((N, N, N), 1.0, UNB3)
+    # the spec's solver and a direct call's are one instance
+    assert spec.build() is s
     for f, r in zip(fs, res):
         want = np.asarray(s.solve(f))
         # same plan, same xla pipeline, batch rows are independent: the
@@ -158,7 +160,7 @@ def test_faulted_request_degrades_without_poisoning_cobatched():
     tenant, and only that batch carries degradation records."""
     spec = _spec()
     fs = _rhs(4, seed=4)
-    plan = faults.FaultPlan([{"kind": "error", "stage": "solve.dispatch",
+    plan = faults.FaultPlan([{"kind": "error", "stage": "dist.dispatch",
                               "count": 1}])
     with PoissonServer(max_batch=4, max_delay_ms=50) as srv:
         futs = [srv.submit(f, spec, tenant=f"t{i}",
@@ -184,7 +186,7 @@ def test_faulted_request_does_not_degrade_clean_warm_plan():
     """The armed batch runs on a fault-token shadow solver: the clean warm
     plan keeps its scheduled relayout for later traffic."""
     spec = _spec(bcs=PER3)
-    plan = faults.FaultPlan([{"kind": "error", "stage": "solve.dispatch",
+    plan = faults.FaultPlan([{"kind": "error", "stage": "dist.dispatch",
                               "count": 1}])
     with PoissonServer(max_batch=1, max_delay_ms=1) as srv:
         [f0] = _rhs(1, seed=5)
@@ -250,7 +252,7 @@ def test_drain_deadline_fails_wedged_requests():
     abandoned, and shutdown returns in bounded time."""
     import time
     spec = _spec(bcs=PER3)
-    plan = faults.FaultPlan([{"kind": "stall", "stage": "solve.dispatch",
+    plan = faults.FaultPlan([{"kind": "stall", "stage": "dist.dispatch",
                               "seconds": 60.0}])
     srv = PoissonServer(max_batch=1, max_delay_ms=1).start()
     fs = _rhs(3, seed=9)

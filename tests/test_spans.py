@@ -129,13 +129,15 @@ def test_spans_of_a_miss_and_a_hit(spans_off):
         assert hit[0].end <= solve.start
         for c in hit[1:4]:
             assert solve.start <= c.start <= c.end <= solve.end
-    # the single-process solver: its solve holds its launch
+    # without a mesh: the same solver on one device, the same spans
     spans.drain()
     kw.pop("mesh"), kw.pop("dtype")
     get_solver(**kw).solve(f).block_until_ready()
     assert [(s.name, s.parent) for s in spans.drain()
-            if s.name in ("flups.launch", "flups.solve")] == [
-        ("flups.launch", "flups.solve"), ("flups.solve", None)]
+            if s.name in ("flups.pad", "flups.launch", "flups.crop",
+                          "flups.solve")] == [
+        ("flups.pad", "flups.solve"), ("flups.launch", "flups.solve"),
+        ("flups.crop", "flups.solve"), ("flups.solve", None)]
 
 
 _LOWER_SCRIPT = r"""
